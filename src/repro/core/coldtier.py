@@ -360,7 +360,7 @@ def _cold_view(shard: ColdShard, *, leaf_cap: int, init: str,
     if init == "approx":
         leaf = min(int(leaf_cap), m)
 
-        def seed(queries):
+        def seed(queries, impl):
             qs = isax.znorm(queries)
             qps = isax.paa(qs, shard.segments)
             qsax = isax.sax_from_paa(qps, shard.cardinality)
@@ -373,7 +373,7 @@ def _cold_view(shard: ColdShard, *, leaf_cap: int, init: str,
             wpos = jnp.take(shard.pos, rows, axis=0)
 
             def one(q, rw, wp):
-                d = ops.euclid_sq(q, rw)
+                d = ops.euclid_sq(q, rw, impl=impl)
                 j = jnp.argmin(d)
                 return d[j], wp[j]
 

@@ -46,23 +46,12 @@ from repro.kernels import ops
 INF = jnp.float32(jnp.inf)
 IMAX = jnp.int32(2**31 - 1)
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 public API
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-
-else:  # jax < 0.6: experimental location, check_rep spelling
-
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 @jax.tree_util.register_dataclass
@@ -78,25 +67,42 @@ class DistIndex:
     cardinality: int = dataclasses.field(metadata=dict(static=True))
 
 
-def dist_index_from(index: ParISIndex, num_shards: int) -> DistIndex:
-    """Pad N to the shard count and materialize index-ordered raw data."""
+def dist_index_from(index: ParISIndex, shardings: DistIndex) -> DistIndex:
+    """Place the index on the mesh, with raw data in index order.
+
+    ``shardings`` comes from :func:`index_shardings`. N is padded to the
+    number of shards along axis 0, and each device's rows of ``raw_sorted``
+    are gathered and moved one shard at a time, so no device ever holds the
+    whole index-ordered copy beside ``index.raw``.
+    """
+    row_sharding = shardings.raw_sorted
+    axes = row_sharding.spec[0]  # one axis name, or a tuple of them
+    axes = (axes,) if isinstance(axes, str) else axes
+    num_shards = int(np.prod([row_sharding.mesh.shape[ax] for ax in axes]))
     n = index.num_series
     padded = -(-n // num_shards) * num_shards
     pad = padded - n
     sax = jnp.pad(index.sax, ((0, pad), (0, 0)))
     # Pad positions carry the NO_POS sentinel so kernels can recognize
     # filler rows (the k-NN kernel masks them out of its result lists; for
-    # 1-NN the +BIG raw filler below already keeps them from winning).
+    # 1-NN the +BIG raw filler already keeps them from winning).
     pos = jnp.pad(index.pos, (0, pad), constant_values=int(NO_POS))
-    raw_sorted = jnp.take(index.raw, index.pos, axis=0)
-    if pad:
+
+    def rows_of(p):
         # Padded rows: +BIG raw values so their distance can never win.
-        filler = jnp.full((pad, index.series_length), 1e9, index.raw.dtype)
-        raw_sorted = jnp.concatenate([raw_sorted, filler], axis=0)
+        raw = jnp.take(index.raw, jnp.maximum(p, 0), axis=0)
+        return jnp.where(p[:, None] >= 0, raw, jnp.float32(1e9))
+
+    shape = (padded, index.series_length)
+    placed = row_sharding.addressable_devices_indices_map(shape)
+    raw_sorted = jax.make_array_from_single_device_arrays(
+        shape, row_sharding,
+        [jax.device_put(rows_of(pos[rows]), dev)
+         for dev, (rows, _) in placed.items()])
     return DistIndex(
-        sax=sax,
+        sax=jax.device_put(sax, shardings.sax),
         raw_sorted=raw_sorted,
-        pos=pos,
+        pos=jax.device_put(pos, shardings.pos),
         series_length=index.series_length,
         segments=index.segments,
         cardinality=index.cardinality,
@@ -428,8 +434,10 @@ def _local_batch_search(
     # Pre-gather candidates OUTSIDE the while_loop (see the note in
     # _local_exact_search: in-loop data-dependent gathers miscompile under
     # shard_map on older jax, and contiguous slices are TPU-friendly).
-    raw_sel = jnp.take(raw_l, order, axis=0)  # (Q, padded, n)
-    pos_sel = jnp.take(pos_l, order, axis=0)  # (Q, padded)
+    # top_k indices are in bounds: "clip" skips the fill-mode select, which
+    # costs a second (Q, padded, n) buffer.
+    raw_sel = jnp.take(raw_l, order, axis=0, mode="clip")  # (Q, padded, n)
+    pos_sel = jnp.take(pos_l, order, axis=0, mode="clip")  # (Q, padded)
 
     def cond(st):
         r, bsf, *_ = st
@@ -641,8 +649,9 @@ def _local_batch_knn(
             [order, jnp.zeros((n_q, padded - sel_len), jnp.int32)], axis=1)
         lb_sorted = jnp.concatenate(
             [lb_sorted, jnp.full((n_q, padded - sel_len), INF)], axis=1)
-    raw_sel = jnp.take(raw_l, order, axis=0)  # pre-gather (see 1-NN note)
-    pos_sel = jnp.take(pos_l, order, axis=0)
+    # pre-gather, in-bounds indices (see the 1-NN note)
+    raw_sel = jnp.take(raw_l, order, axis=0, mode="clip")
+    pos_sel = jnp.take(pos_l, order, axis=0, mode="clip")
 
     def merge(loc_d, loc_p, cand_pos, d):
         d = jnp.where(dedup_mask(cand_pos, loc_d, loc_p), INF, d)

@@ -61,7 +61,7 @@ an :class:`EngineView` hook bundle::
 
     exact_*_batch / make_batch_engine        exact_*_batch_packed
         |                                        |
-    _engine_for (per-index jit cache)        _packed_engine_for /
+    _engine_for (index as jit argument)      _packed_engine_for /
         |                                    packed_engine_args
         v                                        v
     _index_view: identity positions          _packed_view: gpos global
@@ -73,8 +73,9 @@ an :class:`EngineView` hook bundle::
                              v
                _engine_core(view, queries, ...)
 
-The single-index adapters close over the index arrays as jit constants
-(fastest per call); :func:`packed_engine_args` instead takes the packed
+The single-index engines take the index arrays as jit ARGUMENTS (a
+closed-over array would be lowered as a constant, which at chip scale no
+host can compile); :func:`packed_engine_args` likewise takes the packed
 buffers as ARGUMENTS, so an incrementally grown view with stable
 capacity (``core.ingest.IncrementalPacker``) reuses one compiled engine
 across snapshot swaps. Adding an engine feature (new selection modes,
@@ -287,7 +288,8 @@ def bucket_window_start(bucket_offsets: jax.Array, keys: jax.Array,
 
 
 def approx_search(
-    index: ParISIndex, query: jax.Array, leaf_cap: int = 256
+    index: ParISIndex, query: jax.Array, leaf_cap: int = 256,
+    impl: str = "auto",
 ) -> tuple:
     """Initial BSF: true distances over the query's root-bucket neighborhood.
 
@@ -307,13 +309,14 @@ def approx_search(
         index.bucket_offsets, key, leaf_cap, index.num_series)
     window = jax.lax.dynamic_slice_in_dim(index.pos, s, leaf_cap)
     raws = jnp.take(index.raw, window, axis=0)
-    d = ops.euclid_sq(q, raws)
+    d = ops.euclid_sq(q, raws, impl=impl)
     j = jnp.argmin(d)
     return d[j], window[j]
 
 
 def approx_search_batch(
-    index: ParISIndex, queries: jax.Array, leaf_cap: int = 256
+    index: ParISIndex, queries: jax.Array, leaf_cap: int = 256,
+    impl: str = "auto",
 ) -> tuple:
     """Batched :func:`approx_search`: (Q, n) queries -> ((Q,) bsf, (Q,) pos).
 
@@ -331,7 +334,7 @@ def approx_search_batch(
     def one(q, si):
         window = jax.lax.dynamic_slice_in_dim(index.pos, si, leaf_cap)
         raws = jnp.take(index.raw, window, axis=0)
-        d = ops.euclid_sq(q, raws)
+        d = ops.euclid_sq(q, raws, impl=impl)
         j = jnp.argmin(d)
         return d[j], window[j]
 
@@ -434,8 +437,8 @@ class EngineView:
       gather_raw    file positions -> raw series rows; a clipped gather,
                     so a :data:`NO_POS` sentinel reads row 0 harmlessly —
                     its +inf lower bound keeps it outside every mask
-      seed          ``None`` starts every BSF at +inf; else (Q, n) queries
-                    -> ((Q,) bsf, (Q,) pos, leaf reads) — the
+      seed          ``None`` starts every BSF at +inf; else ((Q, n) queries,
+                    impl) -> ((Q,) bsf, (Q,) pos, leaf reads) — the
                     approx-search seeding of the single-index path
     """
 
@@ -470,8 +473,8 @@ def _index_view(
     if init == "approx":
         leaf = min(int(leaf_cap), index.num_series)
 
-        def seed(queries):
-            bsf0, pos0 = approx_search_batch(index, queries, leaf)
+        def seed(queries, impl):
+            bsf0, pos0 = approx_search_batch(index, queries, leaf, impl)
             return bsf0, pos0, leaf
     else:
         seed = None
@@ -565,7 +568,7 @@ def _engine_core(
         )
         reads0 = jnp.zeros((n_q,), jnp.int32)
     elif view.seed is not None:
-        bsf0, pos0, leaf = view.seed(queries)
+        bsf0, pos0, leaf = view.seed(queries, impl)
         top_d0 = jnp.concatenate(
             [bsf0[:, None], jnp.full((n_q, k - 1), INF)], axis=1
         )
@@ -1072,9 +1075,9 @@ def packed_engine_args(
 ) -> tuple:
     """Shape-stable fused engine: packed buffers as jit ARGUMENTS.
 
-    The per-object engines (:func:`_packed_engine_for`, ``_engine_for``)
-    close over their arrays as baked XLA constants — fastest per call, but
-    every new snapshot's packed view costs a fresh trace + compile. This
+    The per-object packed engines (:func:`_packed_engine_for`) close over
+    their arrays as baked XLA constants, so every new snapshot's packed
+    view costs a fresh trace + compile. This
     entry point instead traces per (buffer shapes, statics): an
     incrementally grown packed view whose capacity is stable across
     snapshot swaps (``core.ingest.IncrementalPacker`` doubles capacity and
@@ -1141,23 +1144,8 @@ def exact_knn_batch_packed(
     return top_d, top_p
 
 
-def _seed_fn_for(index: ParISIndex, leaf: int):
-    """Cached jitted bucket-window seeder for one index.
-
-    Shares the per-index ``_engines`` cache (and its lifetime argument);
-    keyed separately from the engine statics.
-    """
-    cache = getattr(index, "_engines", None)
-    if cache is None:
-        cache = {}
-        object.__setattr__(index, "_engines", cache)
-    key = ("seed", leaf)
-    fn = cache.get(key)
-    if fn is None:
-        fn = jax.jit(lambda queries: approx_search_batch(
-            index, queries, leaf))
-        cache[key] = fn
-    return fn
+_approx_search_batch_jit = jax.jit(
+    approx_search_batch, static_argnames=("leaf_cap", "impl"))
 
 
 def packed_seed(components, queries, leaf_cap: int = 256) -> tuple:
@@ -1185,8 +1173,8 @@ def packed_seed(components, queries, leaf_cap: int = 256) -> tuple:
                          "component")
     ix, off = max(comps, key=lambda c: c[0].num_series)
     leaf = min(int(leaf_cap), ix.num_series)
-    seed_d, seed_p = _seed_fn_for(ix, leaf)(
-        jnp.asarray(queries, jnp.float32))
+    seed_d, seed_p = _approx_search_batch_jit(
+        ix, jnp.asarray(queries, jnp.float32), leaf_cap=leaf)
     return seed_d, seed_p.astype(jnp.int32) + jnp.int32(off)
 
 
@@ -1308,76 +1296,46 @@ def exact_search_batch_packed(
     return SearchResult(top_d[:, 0], top_p[:, 0], reads, updates, rounds)
 
 
-# Per-index jitted engines. Closing over the index arrays (instead of
-# passing them as jit arguments) lets XLA treat them as baked constants —
-# on CPU an argument index costs a relayout copy of the big arrays on
-# EVERY call (~100ms at 50k x 256 f32). The cache hangs off the index
-# object itself (the jitted closure strongly references the index arrays,
-# so any external cache would pin dead indices; attached to the index, the
-# engines share its lifetime exactly).
+# Per-index engines take the index arrays as jit ARGUMENTS. A closed-over
+# array is lowered as an HLO constant: at chip scale (gigabytes of raw
+# series) lowering and compiling such a program exhausts the host's
+# memory. As arguments, jit's own cache is keyed by shapes, so every
+# same-shaped index (the router's equal shards) shares one compiled engine.
+
+
+@functools.partial(jax.jit, static_argnames=("statics",))
+def _index_engine(index: ParISIndex, queries: jax.Array,
+                  eps_factor_sq: Optional[jax.Array] = None,
+                  budget_rounds: Optional[jax.Array] = None, *,
+                  statics: tuple) -> tuple:
+    k, round_size, leaf_cap, sort, select, impl, init = statics[:7]
+    blocks = statics[8] if len(statics) > 8 else None
+    view = _index_view(index, leaf_cap=leaf_cap, init=init, blocks=blocks)
+    return _engine_core(
+        view, queries, k=k, round_size=round_size, sort=sort, select=select,
+        impl=impl, eps_factor_sq=eps_factor_sq, budget_rounds=budget_rounds,
+    )
 
 
 def _engine_for(index: ParISIndex, statics: tuple):
-    """Cached per-index jitted engine for a statics tuple.
+    """The per-index engine callable for a statics tuple.
 
     ``statics = (k, round_size, leaf_cap, sort, select, impl, init)``
-    compiles the exact engine (historical 5-tuple return); appending
-    ``True`` — ``(..., init, True)`` — compiles the TIERED variant, whose
-    closure takes ``(queries, eps_factor_sq, budget_rounds)`` as traced
-    arguments and returns the 6-tuple with the achieved factor. Tier
-    parameters being traced is the point: ONE compiled tiered engine per
-    (index, shape) serves every epsilon and budget in mixed batches.
-    A ninth element — ``(..., init, tiered, (block_q, block_n))`` —
-    carries an explicit kernel block-shape override (None members resolve
-    through the tuning table); it is part of the cache key, so two block
-    shapes compile two engines.
+    gives the exact engine ``fn(queries)`` (historical 5-tuple return);
+    appending ``True`` — ``(..., init, True)`` — the TIERED variant
+    ``fn(queries, eps_factor_sq, budget_rounds)``, which returns the
+    6-tuple with the achieved factor. Tier parameters being traced is the
+    point: ONE compiled tiered engine per shape serves every epsilon and
+    budget in mixed batches. A ninth element — ``(..., init, tiered,
+    (block_q, block_n))`` — carries an explicit kernel block-shape
+    override (None members resolve through the tuning table); it is part
+    of jit's cache key, so two block shapes compile two engines.
+
+    It stays a function of ``(index, statics)`` because that is the
+    ``engine_for`` hook :func:`make_batch_engine` shares with
+    ``coldtier._cold_engine_for``, and the seam the tuning tests spy on.
     """
-    cache = getattr(index, "_engines", None)
-    if cache is None:
-        cache = {}
-        # frozen dataclass: fields are immutable but non-field attributes
-        # (invisible to the pytree flatten) can still be attached.
-        object.__setattr__(index, "_engines", cache)
-    fn = cache.get(statics)
-    if fn is not None:
-        return fn
-    k, round_size, leaf_cap, sort, select, impl, init = statics[:7]
-    tiered = len(statics) > 7 and statics[7]
-    blocks = statics[8] if len(statics) > 8 else None
-
-    if tiered:
-        @jax.jit
-        def fn(queries, eps_factor_sq, budget_rounds):
-            view = _index_view(
-                index, leaf_cap=leaf_cap, init=init, blocks=blocks)
-            return _engine_core(
-                view,
-                queries,
-                k=k,
-                round_size=round_size,
-                sort=sort,
-                select=select,
-                impl=impl,
-                eps_factor_sq=eps_factor_sq,
-                budget_rounds=budget_rounds,
-            )
-    else:
-        @jax.jit
-        def fn(queries):
-            view = _index_view(
-                index, leaf_cap=leaf_cap, init=init, blocks=blocks)
-            return _engine_core(
-                view,
-                queries,
-                k=k,
-                round_size=round_size,
-                sort=sort,
-                select=select,
-                impl=impl,
-            )
-
-    cache[statics] = fn
-    return fn
+    return functools.partial(_index_engine, index, statics=statics)
 
 
 def _batch_engine(
@@ -1426,9 +1384,9 @@ def make_batch_engine(
     """Build a reusable, shape-stable batch engine over one index.
 
     The factory behind every streaming caller (``SearchRequestBatcher``,
-    ``ShardedSearchRouter``): it resolves the per-index jitted closure once
-    (through ``_engine_for``'s cache, shared with direct ``exact_*_batch``
-    calls) and wraps it so any (Q, n) call is padded up to the power-of-two
+    ``ShardedSearchRouter``): it resolves the per-index jitted engine once
+    (``_engine_for``, whose compiled programs direct ``exact_*_batch``
+    calls share) and wraps it so any (Q, n) call is padded up to the power-of-two
     bucket shape (pad rows repeat row 0 and are discarded) — one trace per
     bucket instead of one per arrival count, and a router can stamp out S
     per-shard engines without retracing per query shape.
@@ -1628,7 +1586,7 @@ def _exact_search_impl(
 ) -> SearchResult:
     n_series = index.num_series
     q, qp = _query_paa(index, query)
-    bsf0, pos0 = approx_search(index, query, leaf_cap)
+    bsf0, pos0 = approx_search(index, query, leaf_cap, impl)
     bpp = isax.padded_breakpoints(index.cardinality)
 
     # --- LBC phase: one vectorized pass over the whole SAX array. ---
@@ -1738,7 +1696,7 @@ def _nb_exact_search_impl(
 ) -> SearchResult:
     n_series = index.num_series
     q, qp = _query_paa(index, query)
-    bsf0, pos0 = approx_search(index, query, leaf_cap)
+    bsf0, pos0 = approx_search(index, query, leaf_cap, impl)
     bpp = isax.padded_breakpoints(index.cardinality)
     lb = ops.lower_bound_sq(qp, index.sax, bpp, index.series_length, impl=impl)
 

@@ -3,9 +3,14 @@
 This is ParIS+'s flagship SIMD contribution adapted to the TPU VPU. The paper
 evaluates the 3-way branch (query PAA ABOVE / BELOW / IN the iSAX region) on
 all 8 AVX lanes and mask-combines the results; here the same branch-free
-algebra runs on 8x128-lane vector registers over VMEM-resident tiles, and the
-breakpoint dictionary lookups become either a VMEM gather or an MXU one-hot
-matmul (layout/version chosen by ``ops.py``).
+algebra runs on 8x128-lane vector registers over VMEM-resident tiles.
+
+The breakpoint dictionary lookup (symbol -> region bounds) is a one-hot x
+table product on the MXU (:func:`_bounds`): Mosaic lowers no 1-D gather.
+It runs at ``Precision.HIGHEST``: each output column has exactly one
+nonzero term, so the f32 product is exact, whereas the default bf16 pass
+would round a breakpoint and could raise a bound above the true distance —
+a silent pruning error.
 
 Baseline layout: SAX tiles of shape (block_n, w) uint8; w=16 symbols sit on
 the lane axis. The optimized layout (``transposed=True``) stores SAX as
@@ -21,89 +26,146 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core import isax
+
+_TABLE_ROWS = 8  # row 0: lower bounds, row 1: upper bounds, rest zero
 
 
-def _lb_kernel_rows(q_ref, bl_ref, bu_ref, sax_ref, o_ref, *, scale: float):
+def bounds_table(bp_padded: jax.Array) -> jax.Array:
+    """(card+1,) padded breakpoints -> (8, card_pad) f32 lookup table.
+
+    Row 0 holds each symbol's lower breakpoint, row 1 its upper one; the
+    symbol axis is zero-padded to a lane multiple (no symbol selects it).
+    Infinite end breakpoints are clipped to ``±isax.BIG``: the lookup
+    multiplies every entry by 0 or 1, and ``inf * 0`` would be NaN.
+    """
+    card = bp_padded.shape[0] - 1
+    card_pad = -(-card // 128) * 128
+    tab = jnp.zeros((_TABLE_ROWS, card_pad), jnp.float32)
+    bp = jnp.clip(bp_padded.astype(jnp.float32), -isax.BIG, isax.BIG)
+    return tab.at[0, :card].set(bp[:-1]).at[1, :card].set(bp[1:])
+
+
+def _bounds(sym_row: jax.Array, tab: jax.Array) -> tuple:
+    """(1, bn) int32 symbols -> ((1, bn) lower, (1, bn) upper) bounds."""
+    card_pad, bn = tab.shape[1], sym_row.shape[1]
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (card_pad, bn), 0)
+              == sym_row).astype(jnp.float32)
+    lohi = jax.lax.dot(tab, onehot, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)  # (8, bn)
+    return lohi[0:1], lohi[1:2]
+
+
+def _fill_bounds(sym, tab, lo_ref, hi_ref):
+    """(w, bn) int32 symbols -> per-segment bounds in (w, bn) VMEM refs."""
+    for j in range(sym.shape[0]):
+        lo, hi = _bounds(sym[j:j + 1], tab)
+        lo_ref[j:j + 1, :] = lo
+        hi_ref[j:j + 1, :] = hi
+
+
+def _lb_kernel_rows(q_ref, tab_ref, sax_ref, o_ref, lo_ref, hi_ref, *,
+                    scale: float):
     """Tile layout (block_n, w): symbols on lanes. One output per sublane row."""
-    sym = sax_ref[...].astype(jnp.int32)  # (bn, w)
-    # Dictionary lookups: padded-breakpoint tables live in VMEM (257 floats).
-    bl = bl_ref[...][0]  # (card+1,)
-    bu = bu_ref[...][0]
-    lo = jnp.take(bl, sym, axis=0)  # (bn, w)
-    hi = jnp.take(bu, sym, axis=0)
-    q = q_ref[...][0][None, :]  # (1, w) broadcast over the tile
-    above = q - hi
-    below = lo - q
+    _fill_bounds(sax_ref[...].astype(jnp.int32).T, tab_ref[...], lo_ref,
+                 hi_ref)
+    q = q_ref[...]  # (w, 1)
     # Paper's three masked branches, combined without control flow.
-    d = jnp.maximum(jnp.maximum(above, below), 0.0)
-    o_ref[...] = scale * jnp.sum(d * d, axis=-1, keepdims=True)
+    d = jnp.maximum(jnp.maximum(q - hi_ref[...], lo_ref[...] - q), 0.0)
+    o_ref[...] = scale * jnp.sum((d * d).T, axis=-1, keepdims=True)
 
 
-def _lb_kernel_cols(q_ref, bl_ref, bu_ref, sax_ref, o_ref, *, scale: float):
+def _lb_kernel_cols(q_ref, tab_ref, sax_ref, o_ref, lo_ref, hi_ref, *,
+                    scale: float):
     """Tile layout (w, block_n): candidates on lanes (optimized layout)."""
-    sym = sax_ref[...].astype(jnp.int32)  # (w, bn)
-    bl = bl_ref[...][0]
-    bu = bu_ref[...][0]
-    lo = jnp.take(bl, sym, axis=0)
-    hi = jnp.take(bu, sym, axis=0)
-    q = q_ref[...][0][:, None]  # (w, 1)
-    d = jnp.maximum(jnp.maximum(q - hi, lo - q), 0.0)
+    _fill_bounds(sax_ref[...].astype(jnp.int32), tab_ref[...], lo_ref,
+                 hi_ref)
+    q = q_ref[...]  # (w, 1)
+    d = jnp.maximum(jnp.maximum(q - hi_ref[...], lo_ref[...] - q), 0.0)
     o_ref[...] = scale * jnp.sum(d * d, axis=0, keepdims=True)
 
 
-def _lb_kernel_batch(q_ref, bl_ref, bu_ref, sax_ref, o_ref, *, scale: float):
-    """Batched tile: queries on sublanes, candidates on lanes.
+def _lb_batch_tile(q_ref, tab_ref, sax_ref, lo_ref, hi_ref, *, scale: float):
+    """(block_q, w) queries x (w, block_n) SAX tile -> (block_q, block_n).
 
-    q_ref (block_q, w) x sax_ref (w, block_n) -> o_ref (block_q, block_n).
-    The breakpoint gathers run once per SAX tile and are shared by every
-    query row in the block — the whole point of the fused (Q x N) kernel:
-    the SAX array streams through VMEM once per *batch*, not once per query.
+    The grid runs query blocks innermost, so the breakpoint lookups run
+    once per SAX tile (on the first query block, into VMEM scratch) and are
+    shared by every query of the batch: the SAX array streams through VMEM
+    once per *batch*, not once per query.
     """
-    sym = sax_ref[...].astype(jnp.int32)  # (w, bn)
-    bl = bl_ref[...][0]
-    bu = bu_ref[...][0]
-    lo = jnp.take(bl, sym, axis=0)  # (w, bn) — hoisted, query-independent
-    hi = jnp.take(bu, sym, axis=0)
+    w = q_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        _fill_bounds(sax_ref[...].astype(jnp.int32), tab_ref[...], lo_ref,
+                     hi_ref)
+
     q = q_ref[...]  # (bq, w)
-    w = q.shape[-1]
-    acc = jnp.zeros((q.shape[0], sym.shape[1]), jnp.float32)
+    acc = jnp.zeros((q.shape[0], lo_ref.shape[1]), jnp.float32)
     for j in range(w):  # w is 8-32: unrolled VPU ops, no (bq, w, bn) blowup
-        qj = q[:, j][:, None]  # (bq, 1)
-        d = jnp.maximum(jnp.maximum(qj - hi[j][None, :], lo[j][None, :] - qj), 0.0)
+        qj = q[:, j:j + 1]  # (bq, 1)
+        d = jnp.maximum(
+            jnp.maximum(qj - hi_ref[j:j + 1, :], lo_ref[j:j + 1, :] - qj), 0.0)
         acc = acc + d * d
-    o_ref[...] = scale * acc
+    return scale * acc
 
 
-def _lb_kernel_batch_masked(
-    q_ref, bl_ref, bu_ref, sax_ref, len_ref, o_ref, *, scale: float
-):
+def _lb_kernel_batch(q_ref, tab_ref, sax_ref, o_ref, lo_ref, hi_ref, *,
+                     scale: float):
+    o_ref[...] = _lb_batch_tile(q_ref, tab_ref, sax_ref, lo_ref, hi_ref,
+                                scale=scale)
+
+
+def _lb_kernel_batch_masked(len_ref, q_ref, tab_ref, sax_ref, o_ref, lo_ref,
+                            hi_ref, *, scale: float):
     """Batched tile over a *packed multi-component* SAX array.
 
     Same algebra as ``_lb_kernel_batch``, plus a per-block validity count:
     the packed layout (``core.search.pack_components``) pads every
     component's leaf-sorted run to a block_n multiple so an append can
     extend the buffer without moving earlier components' rows, and
-    ``len_ref`` carries how many lanes of THIS block are real rows. Pad
-    lanes come back +inf, so no
+    ``len_ref`` (scalar-prefetched into SMEM) carries how many lanes of
+    each block are real rows. Pad lanes come back +inf, so no
     downstream selection (top_k, round masks, fallback scan) can ever pick
     one — the kernel, not the caller, owns the component boundaries.
     """
-    sym = sax_ref[...].astype(jnp.int32)  # (w, bn)
-    bl = bl_ref[...][0]
-    bu = bu_ref[...][0]
-    lo = jnp.take(bl, sym, axis=0)  # hoisted, query-independent
-    hi = jnp.take(bu, sym, axis=0)
-    q = q_ref[...]  # (bq, w)
-    w = q.shape[-1]
-    acc = jnp.zeros((q.shape[0], sym.shape[1]), jnp.float32)
-    for j in range(w):
-        qj = q[:, j][:, None]
-        d = jnp.maximum(
-            jnp.maximum(qj - hi[j][None, :], lo[j][None, :] - qj), 0.0)
-        acc = acc + d * d
+    acc = _lb_batch_tile(q_ref, tab_ref, sax_ref, lo_ref, hi_ref,
+                         scale=scale)
     lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
     o_ref[...] = jnp.where(
-        lane < len_ref[0, 0], scale * acc, jnp.float32(jnp.inf))
+        lane < len_ref[pl.program_id(0)], acc, jnp.float32(jnp.inf))
+
+
+def _batch_call(kernel, nq, w, n, card_pad, block_q, block_n, n_prefetch,
+                interpret):
+    """pallas_call for the (N-block, Q-block) batch grid, Q innermost.
+
+    Index maps take (j, i) grid indices plus the scalar-prefetch refs.
+    """
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(n // block_n, nq // block_q),
+            in_specs=[
+                pl.BlockSpec((block_q, w), lambda j, i, *_: (i, 0)),
+                pl.BlockSpec((_TABLE_ROWS, card_pad),
+                             lambda j, i, *_: (0, 0)),
+                pl.BlockSpec((w, block_n), lambda j, i, *_: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((block_q, block_n),
+                                   lambda j, i, *_: (i, j)),
+            scratch_shapes=[pltpu.VMEM((w, block_n), jnp.float32)] * 2,
+        ),
+        out_shape=jax.ShapeDtypeStruct((nq, n), jnp.float32),
+        # The scratch lookups are filled on the first query block of each
+        # N block, so the query axis must run in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )
 
 
 @functools.partial(
@@ -142,25 +204,12 @@ def lower_bound_sq_multi_pallas(
         raise ValueError(
             f"block_len {block_len.shape} != ({n // block_n},)")
     scale = float(series_length) / float(w)
-    card1 = bp_padded.shape[0] - 1
-    bl = bp_padded[:-1][None, :]
-    bu = bp_padded[1:][None, :]
-    len2d = block_len.astype(jnp.int32)[None, :]  # (1, n_blocks)
-    grid = (nq // block_q, n // block_n)
-    return pl.pallas_call(
-        functools.partial(_lb_kernel_batch_masked, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, card1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i, j: (0, 0)),
-            pl.BlockSpec((w, block_n), lambda i, j: (0, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nq, n), jnp.float32),
-        interpret=interpret,
-    )(query_paa.astype(jnp.float32), bl, bu, sax_t, len2d)
+    tab = bounds_table(bp_padded)
+    kernel = functools.partial(_lb_kernel_batch_masked, scale=scale)
+    call = _batch_call(kernel, nq, w, n, tab.shape[1], block_q, block_n, 1,
+                       interpret)
+    return call(block_len.astype(jnp.int32), query_paa.astype(jnp.float32),
+                tab, sax_t)
 
 
 @functools.partial(
@@ -179,7 +228,7 @@ def lower_bound_sq_batch_pallas(
 ) -> jax.Array:
     """(Q, w) PAA batch x (w, N) sax -> (Q, N) squared lower bounds.
 
-    Grid is (Q/block_q, N/block_n); both must divide exactly (ops.py pads;
+    Grid is (N/block_n, Q/block_q); both must divide exactly (ops.py pads;
     padded rows/cols produce garbage the caller slices off). Query blocks sit
     on the sublane axis so all 8 sublanes do useful work, candidates on the
     128-wide lanes (the optimized transposed layout).
@@ -193,24 +242,11 @@ def lower_bound_sq_batch_pallas(
             f"(Q={nq}, N={n}) not multiples of ({block_q}, {block_n})"
         )
     scale = float(series_length) / float(w)
-    card1 = bp_padded.shape[0] - 1
-    bl = bp_padded[:-1][None, :]
-    bu = bp_padded[1:][None, :]
-    grid = (nq // block_q, n // block_n)
-    out = pl.pallas_call(
-        functools.partial(_lb_kernel_batch, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, card1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i, j: (0, 0)),
-            pl.BlockSpec((w, block_n), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nq, n), jnp.float32),
-        interpret=interpret,
-    )(query_paa.astype(jnp.float32), bl, bu, sax_t)
-    return out
+    tab = bounds_table(bp_padded)
+    kernel = functools.partial(_lb_kernel_batch, scale=scale)
+    call = _batch_call(kernel, nq, w, n, tab.shape[1], block_q, block_n, 0,
+                       interpret)
+    return call(query_paa.astype(jnp.float32), tab, sax_t)
 
 
 @functools.partial(
@@ -240,39 +276,32 @@ def lower_bound_sq_pallas(
     if n % block_n:
         raise ValueError(f"N={n} not a multiple of block_n={block_n}")
     scale = float(series_length) / float(w)
-    card1 = bp_padded.shape[0] - 1  # card+1 entries -> card usable intervals
-    bl = bp_padded[:-1][None, :]  # (1, card)
-    bu = bp_padded[1:][None, :]
+    tab = bounds_table(bp_padded)
     grid = (n // block_n,)
-    q2d = query_paa.astype(jnp.float32)[None, :]  # (1, w)
+    q2d = query_paa.astype(jnp.float32)[:, None]  # (w, 1)
 
     if transposed:
         kernel = functools.partial(_lb_kernel_cols, scale=scale)
-        in_specs = [
-            pl.BlockSpec((1, w), lambda i: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i: (0, 0)),
-            pl.BlockSpec((w, block_n), lambda i: (0, i)),
-        ]
+        sax_spec = pl.BlockSpec((w, block_n), lambda i: (0, i))
         out_specs = pl.BlockSpec((1, block_n), lambda i: (0, i))
         out_shape = jax.ShapeDtypeStruct((1, n), jnp.float32)
     else:
         kernel = functools.partial(_lb_kernel_rows, scale=scale)
-        in_specs = [
-            pl.BlockSpec((1, w), lambda i: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i: (0, 0)),
-            pl.BlockSpec((1, card1), lambda i: (0, 0)),
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
-        ]
+        sax_spec = pl.BlockSpec((block_n, w), lambda i: (i, 0))
         out_specs = pl.BlockSpec((block_n, 1), lambda i: (i, 0))
         out_shape = jax.ShapeDtypeStruct((n, 1), jnp.float32)
 
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((w, 1), lambda i: (0, 0)),
+            pl.BlockSpec(tab.shape, lambda i: (0, 0)),
+            sax_spec,
+        ],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((w, block_n), jnp.float32)] * 2,
         interpret=interpret,
-    )(q2d, bl, bu, sax)
+    )(q2d, tab, sax)
     return out.reshape(n)

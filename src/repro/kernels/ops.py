@@ -8,6 +8,9 @@ Every op takes ``impl``:
                    by the CPU benchmarks);
   * ``"sisd"``   — scalar-loop formulation (Table-1 baseline; lower bound only).
 
+Any other string raises ``ValueError``: a typo must not quietly run the
+slow interpret path, nor hide which device did the work.
+
 Wrappers own the ugly parts: padding to block multiples and un-padding
 results, so kernels can assume exact tiling.
 
@@ -38,6 +41,17 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+_IMPLS = ("auto", "pallas", "ref")
+
+
+def _use_ref(impl: str, extra: tuple = ()) -> bool:
+    """Validate ``impl``; True when the jnp reference answers the call."""
+    if impl not in _IMPLS + extra:
+        raise ValueError(
+            f"unknown impl {impl!r}; expected one of {_IMPLS + extra}")
+    return impl == "ref" or (impl == "auto" and not _on_tpu())
+
+
 def _pad_rows(x: jax.Array, mult: int, fill=0):
     n = x.shape[0]
     pad = (-n) % mult
@@ -63,7 +77,7 @@ def lower_bound_sq(
     ``block_n=None`` resolves through the tuning table (registry default
     1024 on a miss); an explicit value always wins.
     """
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl, ("sisd",)):
         return _ref.lower_bound_sq(query_paa, sax, bp_padded, series_length)
     if impl == "sisd":
         return _ref.lower_bound_sq_sisd(query_paa, sax, bp_padded, series_length)
@@ -106,7 +120,7 @@ def lower_bound_sq_batch(
     ``block_q``/``block_n`` left as ``None`` resolve through the tuning
     table (registry defaults 8/1024 on a miss); explicit values win.
     """
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl):
         return _ref.lower_bound_sq_batch(
             query_paa, sax, bp_padded, series_length
         )
@@ -160,7 +174,7 @@ def lower_bound_sq_multi(
         raise ValueError(
             f"block_len has {block_len.shape[0]} entries for "
             f"{n // block_n} blocks")
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl):
         valid = (
             jnp.arange(block_n, dtype=jnp.int32)[None, :]
             < jnp.asarray(block_len, jnp.int32)[:, None]
@@ -193,7 +207,7 @@ def paa_isax(
 
     ``block_b=None`` resolves through the tuning table (default 256).
     """
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl):
         return _ref.paa_isax(series, segments, breakpoints, normalize)
     block_b = tuning.resolve_blocks(
         "paa_isax", q=1, n=series.shape[0], block_b=block_b)["block_b"]
@@ -216,7 +230,7 @@ def euclid_sq(
 
     ``block_b=None`` resolves through the tuning table (default 256).
     """
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl):
         return _ref.euclid_sq(query, data)
     block_b = tuning.resolve_blocks(
         "euclid", q=1, n=data.shape[0], block_b=block_b)["block_b"]
@@ -235,7 +249,7 @@ def euclid_min(
     block_b: Optional[int] = None,
 ) -> tuple:
     """(n,) x (B, n) -> (min squared distance, argmin index)."""
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if _use_ref(impl):
         d = _ref.euclid_sq(query, data)
         i = jnp.argmin(d)
         return d[i], i.astype(jnp.int32)

@@ -6,10 +6,10 @@ raw-series tile is read from HBM into VMEM exactly once and both outputs
 (uint8 symbols + f32 PAA) are produced in-register.
 
 Symbolization is the branch-free compare-and-sum over the breakpoint table
-(symbol = #breakpoints below the PAA value) — the same mask trick as the
-lower-bound kernel, trading a 255-wide compare reduction for zero control
-flow. For card=256 and block_b=256 series of length 256 the working set is
-256*256*4B (raw) + small tables ~ 256KiB, comfortably VMEM-resident.
+(symbol = #breakpoints below the PAA value), one (block_b, w) compare per
+breakpoint read from SMEM. For card=256 and block_b=256 series of length
+256 the working set is 256*256*4B (raw) + small tables ~ 256KiB,
+comfortably VMEM-resident.
 """
 
 from __future__ import annotations
@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _paa_isax_kernel(ts_ref, bp_ref, sax_ref, paa_ref, *, segments: int,
@@ -29,11 +30,20 @@ def _paa_isax_kernel(ts_ref, bp_ref, sax_ref, paa_ref, *, segments: int,
         mu = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean((x - mu) * (x - mu), axis=-1, keepdims=True)
         x = (x - mu) * jax.lax.rsqrt(var + 1e-16)
-    p = jnp.mean(x.reshape(bb, segments, n // segments), axis=-1)  # (bb, w)
-    bp = bp_ref[...][0]  # (card-1,)
-    sym = jnp.sum(
-        (p[..., None] > bp[None, None, :]).astype(jnp.int32), axis=-1
-    )
+    # PAA as per-segment sums over lane slices: a (bb, n) -> (bb, w, n/w)
+    # reshape is a shape cast the chip's compiler refuses.
+    seg = n // segments
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, segments), 1)
+    p = jnp.zeros((bb, segments), jnp.float32)
+    for s in range(segments):
+        col = jnp.sum(x[:, s * seg:(s + 1) * seg], axis=-1, keepdims=True)
+        p = jnp.where(lane == s, col, p)
+    p = p / seg
+    # symbol = #breakpoints below the PAA value; breakpoints are SMEM scalars.
+    sym = jax.lax.fori_loop(
+        0, bp_ref.shape[0],
+        lambda i, acc: acc + (p > bp_ref[i]).astype(jnp.int32),
+        jnp.zeros((bb, segments), jnp.int32))
     sax_ref[...] = sym.astype(jnp.uint8)
     paa_ref[...] = p
 
@@ -54,7 +64,6 @@ def paa_isax_pallas(
     b, n = series.shape
     if b % block_b:
         raise ValueError(f"B={b} not a multiple of block_b={block_b}")
-    ncard = breakpoints.shape[0]
     grid = (b // block_b,)
     kernel = functools.partial(
         _paa_isax_kernel, segments=segments, normalize=normalize
@@ -64,7 +73,7 @@ def paa_isax_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, ncard), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_b, segments), lambda i: (i, 0)),
@@ -75,5 +84,5 @@ def paa_isax_pallas(
             jax.ShapeDtypeStruct((b, segments), jnp.float32),
         ],
         interpret=interpret,
-    )(series, breakpoints[None, :])
+    )(series, breakpoints.astype(jnp.float32))
     return sax, paa

@@ -10,7 +10,7 @@ not the fleet):
   * the datastore is split into self-contained file-order shards
     (:func:`repro.core.index.build_sharded_index`); each shard is served
     by a **replica group** of R interchangeable replicas — same immutable
-    shard index (the jitted engine is shared through the per-index cache),
+    shard index (the replicas share one compiled engine),
     but each replica has its own admission-controlled
     :class:`~repro.serving.search_batcher.SearchRequestBatcher` and its
     own daemon flusher. Placement is least-queue-depth with
@@ -452,7 +452,7 @@ class ShardedSearchRouter:
         # One knob-to-engine mapping for single-batcher and sharded
         # deployments alike: every replica batcher (initial or
         # dynamically added) builds its jitted engine from this same knob
-        # set (the per-index cache dedupes compilation across replicas).
+        # set (same statics and shapes: one compile across replicas).
         self._knobs = dict(
             k=k, max_batch=max_batch, max_wait_ms=max_wait_ms, cfg=cfg,
             round_size=round_size, select=select, impl=impl,

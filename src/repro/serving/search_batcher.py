@@ -15,9 +15,10 @@ is the host-side adapter between the two (the retrieval analogue of
   * flushed queries ride a :func:`repro.core.search.make_batch_engine`
     engine, which pads them to a power-of-two batch shape (pad rows repeat
     a real query and are discarded), so the engine compiles ONE step per
-    bucket shape instead of one per arrival count — the jitted closures
-    come from ``core.search._engine_for``'s per-index cache, shared with
-    every direct ``exact_*_batch`` caller;
+    bucket shape instead of one per arrival count — the jitted engine
+    (``core.search._engine_for``) takes the index as an argument, so its
+    compiled programs are shared with every direct ``exact_*_batch``
+    caller and every same-shaped index;
   * the pending queue is *bounded* (``max_pending`` + ``policy``):
     admission control keeps a traffic burst from growing the queue — and
     the tail latency of everything behind it — without bound. ``block``

@@ -193,6 +193,29 @@ def test_approx_search_tiny_index_regression():
         assert int(ps[i]) == int(w.position)
 
 
+def test_approx_search_uses_callers_impl(monkeypatch):
+    """The seed scan runs the distance kernel the caller chose."""
+    from repro.kernels import ops
+    raw = jnp.asarray(
+        RNG.standard_normal((40, 64)).cumsum(axis=1), jnp.float32)
+    idx = build_index(raw, segments=8)
+    seen = []
+    real = ops.euclid_sq
+
+    def spy(q, data, *, impl="auto", **kw):
+        seen.append(impl)
+        return real(q, data, impl=impl, **kw)
+
+    monkeypatch.setattr(ops, "euclid_sq", spy)
+    approx_search(idx, raw[0], leaf_cap=8, impl="ref")
+    approx_search_batch(idx, raw[:3], leaf_cap=8, impl="pallas")
+    exact_knn_batch(idx, raw[:2], k=2, impl="ref", round_size=16)
+    assert seen and seen[:2] == ["ref", "pallas"]
+    assert set(seen[2:]) == {"ref"}
+    with pytest.raises(ValueError, match="unknown impl"):
+        approx_search(idx, raw[0], leaf_cap=8, impl="Pallas")
+
+
 def test_batch_search_tiny_index():
     raw = jnp.asarray(
         RNG.standard_normal((30, 64)).cumsum(axis=1), jnp.float32)
@@ -222,7 +245,8 @@ from repro.core import isax, index as idx_mod, datagen, distributed as dist
 raw = datagen.random_walk(4096, 128, seed=9)
 index = idx_mod.build_index(jnp.asarray(raw))
 mesh = jax.make_mesh((8,), ("shard",))
-dindex = dist.dist_index_from(index, 8)
+sh = dist.index_shardings(mesh, ("shard",))
+dindex = dist.dist_index_from(index, sh)
 rng = np.random.default_rng(3)
 # cold-BSF regime (weak initial bound) + easy random queries
 qs = np.concatenate([
@@ -248,7 +272,7 @@ for rs in (128, 32):
 tiny_raw = jnp.asarray(
     rng.standard_normal((13, 128)).cumsum(axis=1), np.float32)
 tiny = idx_mod.build_index(tiny_raw)
-dtiny = dist.dist_index_from(tiny, 8)
+dtiny = dist.dist_index_from(tiny, sh)
 step_t = jax.jit(dist.make_distributed_batch_search(
     mesh, ("shard",), series_length=128, round_size=2, leaf_cap=2, k=14))
 res_t = step_t(dtiny, jnp.asarray(qs[:2]))

@@ -27,15 +27,7 @@ from repro.core import isax, index as idx_mod, datagen, distributed as dist
 raw = datagen.random_walk(8192, 128, seed=5)
 index = idx_mod.build_index(jnp.asarray(raw))
 mesh = jax.make_mesh((8,), ("shard",))
-dindex = dist.dist_index_from(index, 8)
-sh = dist.index_shardings(mesh, ("shard",))
-import dataclasses
-dindex = dist.DistIndex(
-    sax=jax.device_put(dindex.sax, sh.sax),
-    raw_sorted=jax.device_put(dindex.raw_sorted, sh.raw_sorted),
-    pos=jax.device_put(dindex.pos, sh.pos),
-    series_length=dindex.series_length, segments=dindex.segments,
-    cardinality=dindex.cardinality)
+dindex = dist.dist_index_from(index, dist.index_shardings(mesh, ("shard",)))
 step = jax.jit(dist.make_distributed_search(mesh, ("shard",),
                                             series_length=128,
                                             round_size=256, leaf_cap=4))
@@ -59,6 +51,36 @@ print("EXACT", ok, "READS", reads_s, reads_nb, reads_s <= reads_nb)
 """)
     assert "EXACT True" in out
     assert out.strip().endswith("True")
+
+
+def test_dist_index_from_places_each_shard_on_its_device():
+    """raw_sorted is made one device-shard at a time: it holds raw[pos]
+    plus +BIG filler rows (N padded to the shard count), each device
+    holding only its rows."""
+    out = _run_subprocess(r"""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import index as idx_mod, datagen, distributed as dist
+raw = datagen.random_walk(1001, 64, seed=3)
+index = idx_mod.build_index(jnp.asarray(raw))
+mesh = jax.make_mesh((4,), ("shard",))
+sh = dist.index_shardings(mesh, ("shard",))
+placed = dist.dist_index_from(index, sh)
+pos = np.asarray(index.pos)
+want_raw = np.concatenate([np.asarray(index.raw)[pos],
+                           np.full((3, 64), 1e9, np.float32)])
+want_pos = np.concatenate([pos, np.full(3, -1, pos.dtype)])
+want_sax = np.concatenate([np.asarray(index.sax),
+                           np.zeros((3, index.segments), np.uint8)])
+same = (np.array_equal(np.asarray(placed.raw_sorted), want_raw)
+        and np.array_equal(np.asarray(placed.pos), want_pos)
+        and np.array_equal(np.asarray(placed.sax), want_sax))
+rows = sorted(s.data.shape[0] for s in placed.raw_sorted.addressable_shards)
+devs = {s.device for s in placed.raw_sorted.addressable_shards}
+print("PLACED", same, rows == [251] * 4, len(devs) == 4,
+      placed.raw_sorted.sharding == sh.raw_sorted,
+      placed.sax.sharding == sh.sax, placed.pos.sharding == sh.pos)
+""", devices=4)
+    assert "PLACED True True True True True True" in out
 
 
 def test_distributed_build_matches_local():

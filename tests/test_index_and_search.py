@@ -118,3 +118,19 @@ def test_search_on_tiny_and_degenerate_inputs():
     want = brute_force(idx, q)
     np.testing.assert_allclose(float(got.dist_sq), float(want.dist_sq),
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("tiered", [False, True])
+def test_engine_takes_index_as_arguments(small_index, tiered):
+    """The engine's program holds no copy of the index: closed-over arrays
+    are lowered as constants, which at chip scale (GBs of raw series) no
+    host can compile."""
+    from repro.core import search
+    statics = (4, 4096, 256, True, "topk", "auto", "approx") + (
+        (True,) if tiered else ())
+    fn = search._engine_for(small_index, statics)
+    args = (jnp.zeros((8, 256), jnp.float32),)
+    if tiered:
+        args += (jnp.ones((8,), jnp.float32), jnp.ones((8,), jnp.int32))
+    text = fn.func.lower(small_index, *args, **fn.keywords).as_text()
+    assert len(text) < small_index.raw.nbytes // 20

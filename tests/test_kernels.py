@@ -108,6 +108,34 @@ def test_lower_bound_multi_pallas_vs_ref(parts, block):
         assert np.all(np.isinf(got[:, pad_rows]))
 
 
+@pytest.mark.parametrize("kernel", ["rows", "cols", "batch", "multi"])
+def test_lower_bound_pallas_inf_padded_table(kernel):
+    """A breakpoint table padded with +-inf gives the same (finite) bounds
+    as the +-BIG padding: the one-hot lookup never multiplies inf by 0."""
+    length, w, card, block = 256, 16, 256, 128
+    series = _series(256, length)
+    bpp = isax.padded_breakpoints(card)
+    bpp_inf = bpp.at[0].set(-jnp.inf).at[-1].set(jnp.inf)
+    sax, _ = ref.paa_isax(series, w, isax.gaussian_breakpoints(card))
+    qps = isax.paa(isax.znorm(_series(3, length)), w)
+    calls = {
+        "rows": lambda t, impl: ops.lower_bound_sq(
+            qps[0], sax, t, length, impl=impl, block_n=block),
+        "cols": lambda t, impl: ops.lower_bound_sq(
+            qps[0], sax, t, length, impl=impl, block_n=block,
+            transposed=True),
+        "batch": lambda t, impl: ops.lower_bound_sq_batch(
+            qps, sax, t, length, impl=impl, block_n=block),
+        "multi": lambda t, impl: ops.lower_bound_sq_multi(
+            qps, sax, t, length, jnp.full((2,), block, jnp.int32),
+            impl=impl, block_n=block),
+    }
+    want = np.asarray(calls[kernel](bpp, "ref"))
+    got = np.asarray(calls[kernel](bpp_inf, "pallas"))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 def test_lower_bound_multi_rejects_bad_table():
     length, w, card = 256, 16, 256
     series = _series(128, length)
@@ -178,3 +206,34 @@ def test_batched_euclid_matches_rowwise():
         row = ref.euclid_sq(qs[i], data)
         np.testing.assert_allclose(np.asarray(mat[i]), np.asarray(row),
                                    rtol=2e-3, atol=2e-2)
+
+
+_OPS = ["lower_bound_sq", "lower_bound_sq_batch", "lower_bound_sq_multi",
+        "paa_isax", "euclid_sq", "euclid_min"]
+
+
+@pytest.mark.parametrize("op,impl", [
+    (op, impl) for op in _OPS for impl in ("interpret", "Pallas", "sisd")
+    if (op, impl) != ("lower_bound_sq", "sisd")])
+def test_unknown_impl_rejected(op, impl):
+    """A misspelt impl raises instead of silently running interpret mode;
+    "sisd" is the lower-bound-only baseline."""
+    length, w = 128, 8
+    series = _series(128, length)
+    bp = isax.gaussian_breakpoints(256)
+    bpp = isax.padded_breakpoints(256)
+    sax, paa = ref.paa_isax(series, w, bp)
+    call = {
+        "lower_bound_sq": lambda: ops.lower_bound_sq(
+            paa[0], sax, bpp, length, impl=impl),
+        "lower_bound_sq_batch": lambda: ops.lower_bound_sq_batch(
+            paa[:2], sax, bpp, length, impl=impl),
+        "lower_bound_sq_multi": lambda: ops.lower_bound_sq_multi(
+            paa[:2], sax, bpp, length, jnp.full((1,), 128, jnp.int32),
+            impl=impl),
+        "paa_isax": lambda: ops.paa_isax(series, bp, w, impl=impl),
+        "euclid_sq": lambda: ops.euclid_sq(series[0], series, impl=impl),
+        "euclid_min": lambda: ops.euclid_min(series[0], series, impl=impl),
+    }[op]
+    with pytest.raises(ValueError, match="unknown impl"):
+        call()

@@ -35,8 +35,7 @@ def test_scan_body_multiplied_by_trip_count():
         jax.ShapeDtypeStruct((trips, d, d), jnp.float32)).compile()
     rep = roofline.analyze(comp.as_text(), 1)
     # XLA's own cost_analysis sees the body once — ours must see it trips x.
-    ca = comp.cost_analysis()  # list of dicts on jax<0.5, dict on newer
-    xla_flops = (ca[0] if isinstance(ca, list) else ca)["flops"]
+    xla_flops = comp.cost_analysis()["flops"]
     assert abs(xla_flops - 2 * d ** 3) < 4 * d * d  # body counted once
     assert abs(rep.flops - trips * 2 * d ** 3) < trips * 4 * d * d
 

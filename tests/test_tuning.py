@@ -263,12 +263,21 @@ def test_table_entry_drives_pallas_call(monkeypatch):
 
 
 def test_engine_override_parity_and_distinct_cache_keys(small_index,
-                                                        clean_table):
+                                                        clean_table,
+                                                        monkeypatch):
     """make_batch_engine: explicit blocks give bit-identical answers and
-    a DISTINCT jit-cache entry (historical statics tuples unchanged)."""
+    a DISTINCT jit-cache key (historical statics tuples unchanged)."""
     rng = np.random.default_rng(7)
     queries = jnp.asarray(
         rng.standard_normal((4, 256)).cumsum(axis=1), jnp.float32)
+    keys = []
+    real = search._engine_for
+
+    def spy(index, statics):
+        keys.append(statics)
+        return real(index, statics)
+
+    monkeypatch.setattr(search, "_engine_for", spy)
     base = search.make_batch_engine(small_index, k=5)
     tuned = search.make_batch_engine(
         small_index, k=5, block_q=4, block_n=512)
@@ -276,9 +285,8 @@ def test_engine_override_parity_and_distinct_cache_keys(small_index,
     d1, p1 = tuned(queries)
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
     np.testing.assert_array_equal(np.asarray(p0), np.asarray(p1))
-    cache = getattr(small_index, "_engines", {})
-    has_blocks = [s for s in cache if len(s) > 8 and s[8] == (4, 512)]
-    plain = [s for s in cache if len(s) <= 8]
+    has_blocks = [s for s in keys if len(s) > 8 and s[8] == (4, 512)]
+    plain = [s for s in keys if len(s) <= 8]
     assert has_blocks and plain
 
 
