@@ -26,10 +26,14 @@ host-side ingestion path at pod scale (slow readers don't stall converters).
 
 Per-stage wall-clock times are recorded so benchmarks can reproduce the
 paper's Figs. 9–13 (stage breakdown, worker sweep, buffer sweep, size sweep).
+Each stage's interval is also a profiler span, ``paris.build.<stage>``
+(read, convert, construct, flush, finalize, assemble), timed by the same
+helper as its ``BuildStats`` field.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import shutil
@@ -57,6 +61,7 @@ class BuildStats:
     construct_time: float = 0.0  # Stage 3: sort/merge into leaf order
     flush_time: float = 0.0  # Stage 3: epoch shard writes
     finalize_time: float = 0.0  # final multi-epoch merge
+    assemble_time: float = 0.0  # raw upload, z-normalisation, index arrays
     total_time: float = 0.0
     epochs: int = 0
     chunks: int = 0
@@ -68,7 +73,11 @@ class BuildStats:
 
     @property
     def overlap_efficiency(self) -> float:
-        """Fraction of CPU work hidden behind I/O (1.0 = fully hidden)."""
+        """Fraction of CPU work hidden behind I/O (1.0 = fully hidden).
+
+        Read, flush, finalize and assemble time is not CPU-stage work, so
+        it is not counted as exposed.
+        """
         busy = self.cpu_time
         if busy <= 0:
             return 1.0
@@ -78,8 +87,26 @@ class BuildStats:
             # overlap instead of a spuriously perfect figure.
             return 0.0
         exposed = max(self.total_time - self.read_time - self.flush_time
-                      - self.finalize_time, 0.0)
+                      - self.finalize_time - self.assemble_time, 0.0)
         return max(0.0, min(1.0, 1.0 - exposed / busy))
+
+
+_STAGE_LOCK = threading.Lock()  # convert stages end on worker threads
+
+
+@contextlib.contextmanager
+def _stage(stats: BuildStats, name: str):
+    """Time one stage interval: the profiler span ``paris.build.<name>``
+    and the same interval added to ``stats.<name>_time``."""
+    field = f"{name}_time"
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(f"paris.build.{name}"):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        with _STAGE_LOCK:
+            setattr(stats, field, getattr(stats, field) + dt)
 
 
 def _host_refine_key(sax: np.ndarray, refine_bits: int, cardinality: int
@@ -201,41 +228,40 @@ class PipelineBuilder:
         self._bp = isax.gaussian_breakpoints(cardinality)
 
     # -- Stage 2 task: ConvertToSAX (+ presort in ParIS+ mode) ------------
-    def _bulk_load(self, chunk_np: np.ndarray, offset: int):
-        t0 = time.perf_counter()
+    def _bulk_load(self, chunk_np: np.ndarray, offset: int,
+                   stats: BuildStats):
         # In ParIS+ mode the incremental "tree building" (presort into leaf
         # order) happens here, overlapped with the Coordinator's next read.
-        keys, sax, pos = bulk_load_chunk(
-            chunk_np, offset,
-            segments=self.segments, cardinality=self.cardinality,
-            refine_bits=self.refine_bits, breakpoints=self._bp,
-            impl=self.impl, presort=self.mode == "paris+",
-        )
-        dt = time.perf_counter() - t0
-        return offset, keys, sax, pos, dt
+        with _stage(stats, "convert"):
+            keys, sax, pos = bulk_load_chunk(
+                chunk_np, offset,
+                segments=self.segments, cardinality=self.cardinality,
+                refine_bits=self.refine_bits, breakpoints=self._bp,
+                impl=self.impl, presort=self.mode == "paris+",
+            )
+        return offset, keys, sax, pos
 
     # -- Stage 3: epoch construction + shard flush -------------------------
     def _construct_epoch(self, runs, epoch_dir: str, stats: BuildStats):
-        t0 = time.perf_counter()
-        # Runs are keyed by file offset so that equal-key ties always break
-        # by original position — the pipeline is byte-identical to the
-        # one-shot build_index() regardless of worker completion order.
-        runs = [r[1:] for r in sorted(runs, key=lambda r: r[0])]
-        if self.mode == "paris+":
-            keys, (sax, pos) = _merge_runs(runs)  # linear merges only
-        else:
-            keys = np.concatenate([r[0] for r in runs])
-            sax = np.concatenate([r[1][0] for r in runs])
-            pos = np.concatenate([r[1][1] for r in runs])
-            order = np.argsort(keys, kind="stable")  # stop-the-world sort
-            keys, sax, pos = keys[order], sax[order], pos[order]
-        stats.construct_time += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        os.makedirs(epoch_dir, exist_ok=True)
-        np.save(os.path.join(epoch_dir, "keys.npy"), keys)
-        np.save(os.path.join(epoch_dir, "sax.npy"), sax)
-        np.save(os.path.join(epoch_dir, "pos.npy"), pos)
-        stats.flush_time += time.perf_counter() - t0
+        with _stage(stats, "construct"):
+            # Runs are keyed by file offset so that equal-key ties always
+            # break by original position — the pipeline is byte-identical
+            # to the one-shot build_index() regardless of worker completion
+            # order.
+            runs = [r[1:] for r in sorted(runs, key=lambda r: r[0])]
+            if self.mode == "paris+":
+                keys, (sax, pos) = _merge_runs(runs)  # linear merges only
+            else:
+                keys = np.concatenate([r[0] for r in runs])
+                sax = np.concatenate([r[1][0] for r in runs])
+                pos = np.concatenate([r[1][1] for r in runs])
+                order = np.argsort(keys, kind="stable")  # stop-the-world
+                keys, sax, pos = keys[order], sax[order], pos[order]
+        with _stage(stats, "flush"):
+            os.makedirs(epoch_dir, exist_ok=True)
+            np.save(os.path.join(epoch_dir, "keys.npy"), keys)
+            np.save(os.path.join(epoch_dir, "sax.npy"), sax)
+            np.save(os.path.join(epoch_dir, "pos.npy"), pos)
         stats.epochs += 1
 
     def build(self, source: SeriesSource):
@@ -258,10 +284,9 @@ class PipelineBuilder:
         ok = False
 
         def collect(fut: Future):
-            offset, keys, sax, pos, dt = fut.result()
+            offset, keys, sax, pos = fut.result()
             with lock:
                 epoch_runs.append((offset, keys, [sax, pos]))
-                stats.convert_time += dt
 
         def flush_epoch(runs):
             # Record the shard dir BEFORE writing so a mid-write failure
@@ -273,12 +298,11 @@ class PipelineBuilder:
         try:
             if self.mode == "serial":
                 for i in range(source.num_chunks):
-                    t0 = time.perf_counter()
-                    chunk, off = source.read(i)
-                    stats.read_time += time.perf_counter() - t0
-                    offset, keys, sax, pos, dt = self._bulk_load(chunk, off)
+                    with _stage(stats, "read"):
+                        chunk, off = source.read(i)
+                    offset, keys, sax, pos = self._bulk_load(
+                        chunk, off, stats)
                     epoch_runs.append((offset, keys, [sax, pos]))
-                    stats.convert_time += dt
                     stats.chunks += 1
                     series_in_mem += len(chunk)
                     if series_in_mem >= mem_limit:
@@ -288,14 +312,13 @@ class PipelineBuilder:
                 with ThreadPoolExecutor(self.n_workers) as pool:
                     pending: List[Future] = []
                     for i in range(source.num_chunks):
-                        t0 = time.perf_counter()
-                        chunk, off = source.read(i)  # Coordinator fills B1
-                        stats.read_time += time.perf_counter() - t0
+                        with _stage(stats, "read"):
+                            chunk, off = source.read(i)  # Coordinator: B1
                         # Double buffering: at most 2 chunks in flight — wait
                         # for the older half before reusing it.
                         while len(pending) >= 2:
                             pending.pop(0).result()
-                        fut = pool.submit(self._bulk_load, chunk, off)
+                        fut = pool.submit(self._bulk_load, chunk, off, stats)
                         fut.add_done_callback(collect)
                         pending.append(fut)
                         stats.chunks += 1
@@ -326,19 +349,23 @@ class PipelineBuilder:
                 return index, stats
 
             # Finalize: merge epoch shards into the CSR index.
-            t0 = time.perf_counter()
-            shards = []
-            for d in epoch_dirs:
-                shards.append((
-                    np.load(os.path.join(d, "keys.npy")),
-                    [np.load(os.path.join(d, "sax.npy")),
-                     np.load(os.path.join(d, "pos.npy"))],
-                ))
-            keys, (sax_sorted, pos_sorted) = merge_runs(shards)
-            stats.finalize_time = time.perf_counter() - t0
-            raw = isax.znorm(jnp.asarray(np.asarray(source.data, np.float32)))
-            index = assemble_index(sax_sorted, pos_sorted, raw,
-                                   self.segments, self.cardinality)
+            with _stage(stats, "finalize"):
+                shards = []
+                for d in epoch_dirs:
+                    shards.append((
+                        np.load(os.path.join(d, "keys.npy")),
+                        [np.load(os.path.join(d, "sax.npy")),
+                         np.load(os.path.join(d, "pos.npy"))],
+                    ))
+                keys, (sax_sorted, pos_sorted) = merge_runs(shards)
+            # Assemble: the raw upload, z-normalisation and the index's
+            # device arrays; waits for the device so the stage is whole.
+            with _stage(stats, "assemble"):
+                raw = isax.znorm(
+                    jnp.asarray(np.asarray(source.data, np.float32)))
+                index = jax.block_until_ready(assemble_index(
+                    sax_sorted, pos_sorted, raw, self.segments,
+                    self.cardinality))
             stats.total_time = time.perf_counter() - t_start
             ok = True
             return index, stats

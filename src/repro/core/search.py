@@ -586,25 +586,22 @@ def _engine_core(
     lb = view.lower_bounds(qps, impl)
 
     # --- Per-query candidate orders. top_k ties break toward lower index,
-    # exactly like a stable ascending argsort of lb. ---
-    if sort:
-        if select == "topk":
-            sel_len = select_len(n_rows, rs)
-        else:
-            sel_len = n_rows
-        neg, order = jax.lax.top_k(-lb, sel_len)
-        order = order.astype(jnp.int32)
-        lb_sel = -neg
-    else:
-        sel_len = n_rows
-        lb_sel = lb
-
+    # exactly like a stable ascending argsort of lb. The ``paris.*`` named
+    # scopes change op metadata only: a profile reads selection, the RDC
+    # loop and the fallback scan by these names. ---
+    sel_len = select_len(n_rows, rs) if sort and select == "topk" else n_rows
     n_rounds = -(-sel_len // rs)
     padded = n_rounds * rs
-    lb_sel_p = _pad_cols(lb_sel, padded, INF)
     if sort:
-        order_p = _pad_cols(order, padded, 0)
+        with jax.named_scope("paris.select"):
+            neg, order = jax.lax.top_k(-lb, sel_len)
+            order = order.astype(jnp.int32)
+            lb_sel = -neg
+            lb_sel_p = _pad_cols(lb_sel, padded, INF)
+            order_p = _pad_cols(order, padded, 0)
     else:
+        lb_sel = lb
+        lb_sel_p = _pad_cols(lb_sel, padded, INF)
         shared_order_p = _pad_to(
             jnp.arange(n_rows, dtype=jnp.int32), padded, 0
         )
@@ -706,12 +703,11 @@ def _engine_core(
            jnp.zeros((n_q,), jnp.int32))
     if tiered:
         st0 = st0 + (jnp.full((n_q,), INF),)
-        r, top_d, top_p, reads, updates, skip_lb = jax.lax.while_loop(
+    with jax.named_scope("paris.rdc"):
+        r, top_d, top_p, reads, updates, *skip = jax.lax.while_loop(
             cond, body, st0)
-        r_main = r
-    else:
-        r, top_d, top_p, reads, updates = jax.lax.while_loop(
-            cond, body, st0)
+    r_main = r
+    skip_lb = skip[0] if tiered else None
 
     if sort and select == "topk" and sel_len < n_rows:
         # Exactness fallback: a query whose worst *selected* bound still
@@ -720,11 +716,14 @@ def _engine_core(
         # re-evaluated every round, so it tightens as BSFs improve. The
         # whole loop (including its padded-copy setup) lives inside a
         # lax.cond: in the common case no query needs it and the branch —
-        # and its buffer copies — are skipped entirely.
+        # and its buffer copies — are skipped entirely. Its scope opens
+        # inside the taken branch, so a batch that skips the scan runs no
+        # op under ``paris.fallback`` (the cond op itself stays outside).
         kth_bound = lb_sel[:, -1]
         all_rounds = -(-n_rows // rs)
         pad_all = all_rounds * rs
 
+        @jax.named_scope("paris.fallback")
         def run_fallback(st):
             idx_all = _pad_to(
                 jnp.arange(n_rows, dtype=jnp.int32), pad_all, 0)
@@ -1403,8 +1402,16 @@ def make_batch_engine(
     are traced), and pad rows ride along with a zero round budget so
     they can never extend the loop.
 
+    ``engine(queries, counts=True)`` (k-NN mode only) appends the
+    engine's work counts to whatever the call returns: the (Q,) raw reads
+    of the real rows and the round count, both copied to the host (pad
+    rows are left out). The answers are those of the plain call; 1-NN
+    mode's ``SearchResult`` carries the counts already.
+
     The returned callable exposes ``engine.bucket(qn)`` — the padded batch
-    shape a Q-query call compiles at (callers use it for pad accounting).
+    shape a Q-query call compiles at (callers use it for pad accounting) —
+    and ``engine.takes_counts`` (True), which a wrapper of the engine that
+    does not pass ``counts`` on leaves unset.
 
     ``engine_for`` swaps the per-index jitted-engine factory: the default
     :func:`_engine_for` serves in-memory :class:`ParISIndex` objects; the
@@ -1442,11 +1449,21 @@ def make_batch_engine(
     def bucket(qn: int) -> int:
         return pow2_bucket(qn, min_bucket)
 
-    def engine(queries, tiers=None):
+    def engine(queries, tiers=None, counts=False):
         qs = jnp.asarray(queries, jnp.float32)
         if qs.ndim != 2:
             raise ValueError(f"engine takes (Q, n) queries, got {qs.shape}")
+        if counts and k is None:
+            raise ValueError(
+                "counts=True is for k-NN mode; the 1-NN SearchResult "
+                "carries raw_reads and rounds")
         qn = qs.shape[0]
+
+        def with_counts(out, reads, rounds):
+            # Host copies, sliced on the host: no per-size device program.
+            if not counts:
+                return out
+            return out + (np.asarray(reads)[:qn], int(rounds))
         if tiers is not None:
             tiers = [as_tier(t) for t in tiers]
             if len(tiers) != qn:
@@ -1479,7 +1496,8 @@ def make_batch_engine(
                     [top_d, jnp.full((qn, k - k_eff), INF)], axis=1)
                 top_p = jnp.concatenate(
                     [top_p, jnp.full((qn, k - k_eff), NO_POS)], axis=1)
-            return top_d, top_p, achieved_epsilon(ach_sq)
+            return with_counts((top_d, top_p, achieved_epsilon(ach_sq)),
+                               reads, rounds)
         top_d, top_p, reads, updates, rounds = fn(qs)
         if k is None:
             return SearchResult(
@@ -1492,11 +1510,12 @@ def make_batch_engine(
                 [top_d, jnp.full((qn, k - k_eff), INF)], axis=1)
             top_p = jnp.concatenate(
                 [top_p, jnp.full((qn, k - k_eff), NO_POS)], axis=1)
-        return top_d, top_p
+        return with_counts((top_d, top_p), reads, rounds)
 
     engine.bucket = bucket
     engine.index = index
     engine.k = k
+    engine.takes_counts = True
     return engine
 
 

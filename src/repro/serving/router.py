@@ -1085,7 +1085,9 @@ class ShardedSearchRouter:
         snapshots, and the hedging/retry/deadline counters the fabric's
         rescue activity — together they let a caller spot saturation,
         a dead replica, or a melting hedge budget without poking
-        internals.
+        internals. Counts are cumulative: a caller that wants a rate
+        over a window takes the difference of two snapshots (as
+        ``chipbench`` does).
         """
         self._shards_rw.acquire_read()
         try:
@@ -1132,7 +1134,6 @@ class ShardedSearchRouter:
                 (sum(s["batch_size_sum"] for s in per)
                  + ret["batch_size_sum"])
                 / max(sum(s["batches"] for s in per) + ret["batches"], 1)),
-            qps=min((s["qps"] for s in per), default=0.0),
             tiered_answered=(sum(s["tiered_answered"] for s in per)
                              + ret["tiered_answered"]),
             achieved_eps_max=max(

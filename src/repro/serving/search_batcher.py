@@ -26,7 +26,7 @@ is the host-side adapter between the two (the retrieval analogue of
     ``reject`` raises :class:`QueueFullError` at the door, and
     ``shed-oldest`` evicts a queued request (failing its future with
     :class:`RequestShedError`) in favor of the new arrival. Queue-depth
-    peaks and shed/reject counts ride next to the qps/latency counters;
+    peaks and shed/reject counts ride next to the latency counters;
   * requests may carry an absolute *deadline* (``submit(q, deadline=t)``,
     monotonic seconds): shedding is then deadline-aware — the victim is
     the request with the least time-to-deadline (an already-expired or
@@ -41,7 +41,9 @@ is the host-side adapter between the two (the retrieval analogue of
     (blackhole: the cohort is consumed and never answered — the
     accepted-then-lost failure mode hedging and deadlines exist for);
   * ``drain()`` answers everything still queued (shutdown / test barrier);
-  * throughput and latency counters ride along (``stats()``).
+  * throughput and latency counters ride along (``stats()``), and each
+    flush is a profiler span (``paris.flush``, ``paris.flush.resolve``)
+    that carries its cohort size, queue wait and the engine's work counts.
 
 Two modes: ``k=None`` answers exact 1-NN through
 :func:`repro.core.search.exact_search_batch` (per-request ``SearchResult``
@@ -66,6 +68,7 @@ import time
 from concurrent.futures import Future
 from typing import List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.index import ParISIndex
@@ -222,7 +225,6 @@ class SearchRequestBatcher:
         self._space = threading.Condition(self._lock)
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
-        self._t0 = time.monotonic()
         self._counters = dict(
             submitted=0, answered=0, batches=0, padded_queries=0,
             flush_full=0, flush_timeout=0, flush_drain=0,
@@ -420,8 +422,21 @@ class SearchRequestBatcher:
                     "deadline passed while the request was queued"))
             if not take:
                 return len(expired)
+        qn = len(take)
+        bucket = self._engine.bucket(qn)
+        waits = [(now - p.t_submit) * 1e3 for p in take]
+        # Profiler spans (free unless a profile is being taken): the
+        # flush with its cohort and the queueing it paid, then the
+        # resolution with the engine's work counts.
+        with jax.profiler.TraceAnnotation(
+                "paris.flush", qn=qn, bucket=bucket, wait_ms_sum=sum(waits),
+                wait_ms_max=max(waits)):
+            return self._run(take, reason, bucket) + len(expired)
+
+    def _run(self, take: List[_Pending], reason: str, bucket: int) -> int:
+        """One engine call for a claimed cohort; resolves its futures."""
+        qn = len(take)
         try:
-            qn = len(take)
             if self._fault_hook is not None:
                 # Chaos instrumentation: may sleep (latency), raise (the
                 # cohort fails typed, below), or blackhole the cohort —
@@ -430,60 +445,79 @@ class SearchRequestBatcher:
                 if self._fault_hook() is False:
                     with self._lock:
                         self._counters["blackholed"] += qn
-                    return qn + len(expired)
-            bucket = self._engine.bucket(qn)
+                    return qn
             qs = np.stack([p.query for p in take])
             tiers = [p.tier for p in take]
-            if any(t.kind != "exact" for t in tiers):
-                # Mixed-tier cohort: ONE tiered engine call answers every
-                # row at its own tier. Exact requests keep their 2-tuple
-                # result shape; tiered requests get achieved_eps appended.
-                d, pos, ach = self._engine(qs, tiers=tiers)
-                d, pos = np.asarray(d), np.asarray(pos)
-                ach = np.asarray(ach)
-                outs = [
-                    (d[i], pos[i], float(ach[i]))
-                    if tiers[i].kind != "exact" else (d[i], pos[i])
-                    for i in range(qn)
-                ]
-            elif self.k is None:
-                outs = _split_search(self._engine(qs), qn)
-                ach = None
+            ach = None
+            if self.k is None:
+                res = self._engine(qs)
+                outs = _split_search(res, qn)
+                counts = dict(reads=int(np.sum(res.raw_reads)),
+                              rounds=int(res.rounds))
             else:
-                out = self._engine(qs)
-                d, pos = np.asarray(out[0]), np.asarray(out[1])
-                outs = [(d[i], pos[i]) for i in range(qn)]
-                ach = None
+                # make_batch_engine's engines append their work counts
+                # (engine.takes_counts). A wrapper that does not pass
+                # ``counts`` on, as the benchmark's fault-injection test
+                # builds, is called plainly: its resolve span has none.
+                kw = ({"counts": True}
+                      if getattr(self._engine, "takes_counts", False) else {})
+                if any(t.kind != "exact" for t in tiers):
+                    # Mixed-tier cohort: ONE tiered engine call answers
+                    # every row at its own tier. Exact requests keep their
+                    # 2-tuple result shape; tiered requests get
+                    # achieved_eps appended.
+                    out = self._engine(qs, tiers=tiers, **kw)
+                    d, pos, ach = (np.asarray(a) for a in out[:3])
+                    outs = [
+                        (d[i], pos[i], float(ach[i]))
+                        if tiers[i].kind != "exact" else (d[i], pos[i])
+                        for i in range(qn)
+                    ]
+                else:
+                    out = self._engine(qs, **kw)
+                    d, pos = np.asarray(out[0]), np.asarray(out[1])
+                    outs = [(d[i], pos[i]) for i in range(qn)]
+                counts = (dict(reads=int(np.sum(out[-2])), rounds=out[-1])
+                          if kw else {})
         except BaseException as e:  # noqa: BLE001 — propagate per request
             for p in take:
                 p.future.set_exception(e)
             raise
-        now = time.monotonic()
-        c = self._counters
-        with self._lock:
-            c[reason] += 1
-            c["batches"] += 1
-            c["batch_size_sum"] += qn
-            c["padded_queries"] += bucket - qn
-            c["answered"] += qn
-            if ach is not None:
-                for i, t in enumerate(tiers):
-                    if t.kind != "exact":
-                        c["tiered_answered"] += 1
-                        c["achieved_eps_sum"] += float(ach[i])
-                        c["achieved_eps_max"] = max(
-                            c["achieved_eps_max"], float(ach[i]))
-            for p in take:
-                lat = (now - p.t_submit) * 1e3
-                c["latency_ms_sum"] += lat
-                c["latency_ms_max"] = max(c["latency_ms_max"], lat)
-        for p, out in zip(take, outs):
-            p.future.set_result(out)
-        return qn + len(expired)
+        with jax.profiler.TraceAnnotation(
+                "paris.flush.resolve", qn=qn,
+                rows=int(self.index.num_series), **counts):
+            now = time.monotonic()
+            c = self._counters
+            with self._lock:
+                c[reason] += 1
+                c["batches"] += 1
+                c["batch_size_sum"] += qn
+                c["padded_queries"] += bucket - qn
+                c["answered"] += qn
+                if ach is not None:
+                    for i, t in enumerate(tiers):
+                        if t.kind != "exact":
+                            c["tiered_answered"] += 1
+                            c["achieved_eps_sum"] += float(ach[i])
+                            c["achieved_eps_max"] = max(
+                                c["achieved_eps_max"], float(ach[i]))
+                for p in take:
+                    lat = (now - p.t_submit) * 1e3
+                    c["latency_ms_sum"] += lat
+                    c["latency_ms_max"] = max(c["latency_ms_max"], lat)
+            for p, out in zip(take, outs):
+                p.future.set_result(out)
+        return qn
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
-        """Counters + derived throughput/latency figures (a shallow copy)."""
+        """Counters + derived latency and batch-size figures (a shallow
+        copy).
+
+        Counts run from construction: a caller that wants a rate over a
+        window takes the difference of two snapshots (as ``chipbench``
+        does for ``answered``, ``batches`` and ``padded_queries``).
+        """
         with self._lock:
             c = dict(self._counters)
             c["queued"] = len(self._pending)
@@ -493,7 +527,6 @@ class SearchRequestBatcher:
         c["batch_size_avg"] = c["batch_size_sum"] / b
         c["achieved_eps_avg"] = (
             c["achieved_eps_sum"] / max(c["tiered_answered"], 1))
-        c["qps"] = c["answered"] / max(time.monotonic() - self._t0, 1e-9)
         return c
 
 

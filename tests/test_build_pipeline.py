@@ -111,6 +111,15 @@ def test_overlap_efficiency_robust_to_zero_total_time():
     assert 0.0 <= done.overlap_efficiency <= 1.0
 
 
+def test_overlap_efficiency_leaves_out_assembly():
+    # Assembling the index (raw upload, z-normalisation, device arrays)
+    # is neither convert nor construct work: it is not exposed CPU time.
+    base = BuildStats(convert_time=2.0, read_time=3.0, total_time=3.5)
+    assembled = BuildStats(convert_time=2.0, read_time=3.0, total_time=5.5,
+                           assemble_time=2.0)
+    assert base.overlap_efficiency == assembled.overlap_efficiency == 0.75
+
+
 def test_merge_runs_requires_runs():
     with pytest.raises(ValueError):
         merge_runs([])

@@ -59,3 +59,43 @@ def test_cache_env_var_wins(tmp_path):
 def test_cache_dir_is_ignored_by_git():
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+
+_KEY = r"""
+import jax, jax.numpy as jnp
+from jax._src import cache_key
+from repro import compile_cache
+compile_cache.enable_compile_cache()
+
+def scoped(name):
+    def f(x):
+        with jax.named_scope(name):
+            return jnp.sin(x) * 3.0
+    return f
+
+def key(f):
+    lowered = jax.jit(f).lower(jnp.arange(7.0))
+    h = cache_key.hashlib.sha256()
+    cache_key._hash_computation(h, lowered._lowering.stablehlo(),
+                                cache_key.IgnoreCallbacks.NO)
+    return h.hexdigest()
+
+def first_caller(name): return key(scoped(name))
+def second_caller(name):
+    return key(scoped(name))
+a = first_caller("paris.a")
+print(a == second_caller("paris.a"), a != first_caller("paris.b"))
+"""
+
+
+def test_cache_key_holds_op_names_but_not_callers():
+    # An executable cached by a build without the engine's named scopes
+    # must not be loaded in place of one with them; which caller compiled
+    # a program first must not change its key.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run([sys.executable, "-c", _KEY], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.split() == ["True", "True"]
