@@ -32,11 +32,18 @@ Batched query answering (beyond-paper; MESSI-style multi-query execution):
   LBC over a query batch    -> :func:`ops.lower_bound_sq_batch` — one fused
                                (Q, N) kernel pass; the SAX array streams
                                through VMEM once per *batch*, not per query.
-  candidate selection       -> per-query ``jax.lax.top_k`` partial selection
-                               (``select="topk"``) of the smallest K bounds
-                               instead of a full argsort, with an exactness
-                               fallback scan that runs only if the K-th bound
-                               still beats a query's BSF at list exhaustion.
+  candidate selection       -> per-query selection of the smallest K
+                               bounds (``select="topk"``, K = N/16) instead
+                               of a full argsort: :func:`select_candidates`.
+                               On the TPU a ``top_k`` this wide is a sort of
+                               the whole row (log^2 N passes), so from 2^18
+                               rows it runs in two stages: strided groups of
+                               2,048 keep their 256 smallest, the union
+                               (N/8) is sorted again, and a check falls back
+                               to the full ``top_k`` where the union may
+                               miss the top K. An exactness fallback scan
+                               runs only if the K-th bound still beats a
+                               query's BSF at list exhaustion.
                                The path is k-safe for k-NN: re-distanced
                                candidates are masked against the current
                                (Q, k) result list by position
@@ -112,6 +119,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
@@ -358,13 +366,91 @@ def _pad_cols(x: jax.Array, size: int, fill) -> jax.Array:
 
 
 def select_len(n: int, round_size: int) -> int:
-    """Per-query candidate-list length for top_k partial selection.
+    """Per-query candidate-list length K of the partial selection.
 
     Shared by the single-host batch engine and the distributed batch kernel:
     the exactness-fallback protocol on both sides assumes the K-th selected
     bound comes from exactly this K, so there is ONE definition.
     """
     return min(n, max(n // 16, 4 * round_size))
+
+
+# Two-stage selection: strided groups of SELECT_GROUP rows keep their
+# SELECT_KEEP smallest bounds each, and the union is sorted again. Engaged
+# only from SELECT_TWO_STAGE_MIN rows, where K = N/16 (select_len).
+SELECT_GROUP = 2048
+SELECT_KEEP = SELECT_GROUP // 8
+SELECT_TWO_STAGE_MIN = 1 << 18
+
+
+def _full_select(lb: jax.Array, sel_len: int) -> tuple:
+    neg, order = jax.lax.top_k(-lb, sel_len)
+    return -neg, order.astype(jnp.int32)
+
+
+def _by_blocks(fn, lb: jax.Array):
+    """``fn`` over blocks of at most 8 rows of ``lb``, outputs joined
+    along the rows: a sort's buffers then hold one block, not the batch."""
+    n_q, n = lb.shape
+    block = math.gcd(n_q, 8)
+    out = jax.lax.map(fn, lb.reshape(n_q // block, block, n))
+    return jax.tree.map(lambda x: x.reshape(n_q, *x.shape[2:]), out)
+
+
+def _group_heads(lb: jax.Array) -> tuple:
+    """(b, N) bounds -> (b, G, SELECT_KEEP) smallest bounds of each strided
+    group and their row ids, ascending by (bound, row)."""
+    b, n = lb.shape
+    groups = -(-n // SELECT_GROUP)
+    shape = (b, groups, SELECT_GROUP)
+    vals = jnp.swapaxes(
+        _pad_cols(lb, groups * SELECT_GROUP, INF).reshape(
+            b, SELECT_GROUP, groups), 1, 2)
+    idx = (jax.lax.broadcasted_iota(jnp.int32, shape, 2) * groups
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    # (bound, row) pairs are distinct, so the sort need not be stable.
+    vals, idx = jax.lax.sort((vals, idx), dimension=2, num_keys=2,
+                             is_stable=False)
+    return vals[:, :, :SELECT_KEEP], idx[:, :, :SELECT_KEEP]
+
+
+def select_candidates(lb: jax.Array, sel_len: int) -> tuple:
+    """(Q, N) bounds -> the ``sel_len`` smallest of each row, ascending.
+
+    Returns ``(lb_sel, order)``, bit-identical to ``lax.top_k(-lb,
+    sel_len)`` negated back: the same values and row indices, ties toward
+    the lower index. On the TPU a ``top_k`` this wide is a sort of the
+    whole row, whose passes grow as log^2 N. Where ``sel_len`` is N/16 and
+    N >= ``SELECT_TWO_STAGE_MIN`` it runs in two stages instead:
+
+    1. Row i = a * G + b is slot a of strided group b (G = N_pad /
+       ``SELECT_GROUP``, +inf padding). Each group is sorted by (bound,
+       index) and keeps its ``SELECT_KEEP`` smallest, 8 queries at a time.
+    2. The union, N_pad / 8 per row, is sorted by (bound, index) and cut
+       to ``sel_len``.
+
+    The union holds the exact top ``sel_len`` when every group's largest
+    kept bound is strictly above the ``sel_len``-th selected one: all rows
+    a group dropped lie above it. If any query of the batch misses that
+    check, a ``lax.cond`` selects with the full ``top_k`` instead, under
+    the ``paris.select.full`` scope (opened in the taken branch only).
+    """
+    n_q, n = lb.shape
+    if sel_len != n // 16 or n < SELECT_TWO_STAGE_MIN:
+        return _full_select(lb, sel_len)
+    vals, idx = _by_blocks(_group_heads, lb)
+    lb_sel, order = jax.lax.sort(
+        (vals.reshape(n_q, -1), idx.reshape(n_q, -1)), dimension=1,
+        num_keys=2, is_stable=False)
+    lb_sel, order = lb_sel[:, :sel_len], order[:, :sel_len]
+    exact = jnp.all(vals[:, :, -1] > lb_sel[:, -1:])
+
+    @jax.named_scope("paris.select.full")
+    def full(lb):
+        return _by_blocks(functools.partial(_full_select, sel_len=sel_len),
+                          lb)
+
+    return jax.lax.cond(exact, lambda lb: (lb_sel, order), full, lb)
 
 
 NO_POS = jnp.int32(-1)  # sentinel position of an unfilled k-NN result slot
@@ -514,10 +600,12 @@ def _engine_core(
     only through the :class:`EngineView` hooks.
 
     ``select="topk"`` keeps only the K smallest bounds per query
-    (K = max(N/16, 4*round_size)); exactness is preserved by a fallback scan
-    over the full row order that only runs for queries whose K-th bound
-    still beats their k-th best distance when the truncated list is
-    exhausted (rare — raw reads are ~1-4% of N on the paper's workloads).
+    (K = max(N/16, 4*round_size)), chosen by :func:`select_candidates` (two
+    sorts of short rows from 2^18 rows up, one ``top_k`` below, which the
+    TPU runs as a sort of the whole row); exactness is preserved by a
+    fallback scan over the full row order that only runs for queries whose
+    K-th bound still beats their k-th best distance when the truncated list
+    is exhausted (rare — raw reads are ~1-4% of N on the paper's workloads).
     The path is k-safe: the fallback (and, under an approx seed, the main
     loop) re-distances already-seen candidates, and for k > 1 every merge
     masks candidates whose position already sits in the result list
@@ -585,18 +673,16 @@ def _engine_core(
     # --- LBC phase: ONE fused (Q, n_rows) pass over the SAX rows. ---
     lb = view.lower_bounds(qps, impl)
 
-    # --- Per-query candidate orders. top_k ties break toward lower index,
-    # exactly like a stable ascending argsort of lb. The ``paris.*`` named
-    # scopes change op metadata only: a profile reads selection, the RDC
+    # --- Per-query candidate orders. Selection ties break toward the lower
+    # index, exactly like a stable ascending argsort of lb. The ``paris.*``
+    # named scopes change op metadata only: a profile reads selection, the RDC
     # loop and the fallback scan by these names. ---
     sel_len = select_len(n_rows, rs) if sort and select == "topk" else n_rows
     n_rounds = -(-sel_len // rs)
     padded = n_rounds * rs
     if sort:
         with jax.named_scope("paris.select"):
-            neg, order = jax.lax.top_k(-lb, sel_len)
-            order = order.astype(jnp.int32)
-            lb_sel = -neg
+            lb_sel, order = select_candidates(lb, sel_len)
             lb_sel_p = _pad_cols(lb_sel, padded, INF)
             order_p = _pad_cols(order, padded, 0)
     else:
@@ -631,7 +717,7 @@ def _engine_core(
         d = jnp.where(dedup_mask(cand_pos, top_d, top_p), INF, d)
         md = jnp.concatenate([top_d, d], axis=1)
         mp = jnp.concatenate([top_p, cand_pos], axis=1)
-        neg_d, sel = jax.lax.top_k(-md, k)  # O(n log k), not a full sort
+        neg_d, sel = jax.lax.top_k(-md, k)  # the TPU sorts the k + rs row
         return -neg_d, jnp.take_along_axis(mp, sel, axis=1)
 
     def cond(st):
@@ -1554,11 +1640,13 @@ def exact_knn_batch(
 ) -> tuple:
     """Batched exact k-NN: (Q, n) -> ((Q, k) dists ascending, (Q, k) pos).
 
-    Rides the partial-selection fast path by default (``select="topk"``,
-    O(N log K) per query instead of a full O(N log N) argsort) with an
-    approx-seeded BSF: row 0 of the result list starts at the query's
-    bucket-window best, rows 1..k-1 at INF. Exactness is kept by the
-    dedup-masked fallback protocol of :func:`_engine_core`.
+    Rides the partial-selection fast path by default (``select="topk"``:
+    the N/16 smallest bounds per query by :func:`select_candidates`, two
+    sorts of short rows where a wide ``top_k`` would sort the whole row on
+    the TPU, instead of a full argsort of all N) with an approx-seeded BSF:
+    row 0 of the result list starts at the query's bucket-window best, rows
+    1..k-1 at INF. Exactness is kept by the dedup-masked fallback protocol
+    of :func:`_engine_core`.
 
     ``k`` is validated: ``k < 1`` raises; ``k > index.num_series`` is
     answered with the ``num_series`` real neighbors and the remaining slots

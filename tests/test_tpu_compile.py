@@ -1,4 +1,5 @@
-"""Every Pallas kernel of the search path compiles for a TPU v5e chip.
+"""Every Pallas kernel of the search path, and the two-stage candidate
+selection, compiles for a TPU v5e chip.
 
 The chip is described, not attached: the TPU compiler installed beside JAX
 compiles for a ``v5e:2x2`` topology from a CPU-only host, with
@@ -21,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import tuning
+from repro.core.search import select_candidates
 from repro.kernels import euclidean, lower_bound, paa_isax
 
 N, W, LENGTH, Q, CARD, ROUND = 1 << 20, 16, 256, 64, 256, 4096
@@ -101,3 +103,15 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
     compiled = _lower(name, spec).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_selection_compiles_for_v5e_within_top_k_memory(one_chip):
+    """The two-stage candidate selection compiles for the chip at the
+    engine's K = N/16, and its temporaries stay below the (bound, index)
+    sort of the whole (Q, N) batch that the one ``top_k`` it replaces
+    holds (its sorts run 8 queries at a time)."""
+    lb = jax.ShapeDtypeStruct((Q, N), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(select_candidates, static_argnums=1).lower(
+        lb, N // 16).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * Q * N * 4, temp
