@@ -6,6 +6,7 @@ from repro.core.index import (
     assemble_index,
     build_index,
     build_sharded_index,
+    build_sharded_index_on_devices,
     empty_index,
 )
 from repro.core.search import (
@@ -51,7 +52,7 @@ from repro.core.ingest import (
 
 __all__ = [
     "ParISIndex", "ShardedIndex", "build_index", "assemble_index",
-    "build_sharded_index", "empty_index",
+    "build_sharded_index", "build_sharded_index_on_devices", "empty_index",
     "PackedComponents", "SearchConfig", "SearchResult", "approx_search",
     "approx_search_batch", "brute_force", "exact_knn", "exact_knn_batch",
     "exact_knn_batch_packed", "exact_search", "exact_search_batch",

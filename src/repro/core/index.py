@@ -25,6 +25,7 @@ which also scans the flat SAX array rather than the tree).
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -228,6 +229,45 @@ def build_sharded_index(index: ParISIndex, num_shards: int) -> ShardedIndex:
             )
         )
     return ShardedIndex(tuple(shards), tuple(bounds))
+
+
+def build_sharded_index_on_devices(
+    shares,
+    segments: int = isax.DEFAULT_SEGMENTS,
+    cardinality: int = isax.DEFAULT_CARDINALITY,
+    *,
+    refine_bits: int = 4,
+    impl: str = "auto",
+) -> ShardedIndex:
+    """Build one shard per device from S raw file-order shares in place.
+
+    Share ``s`` is an (N_s, n) array held by one device; :func:`build_index`
+    runs there, with its constants made on that device too, so no device
+    ever holds another share's rows or the whole collection. Shard ``s``
+    owns the file range that follows the earlier shares (offsets are the
+    cumulative share lengths). Z-normalisation and summarisation are per
+    series and the leaf-order sort is stable, so each shard equals what
+    :func:`build_sharded_index` cuts from an index over the concatenated
+    collection with the same bounds. The builds run in a thread each, so
+    one device's compiles and dispatches do not wait for another's.
+    """
+    shares = list(shares)
+    if not shares:
+        raise ValueError("no shares to build")
+    for raw in shares:
+        if len(raw.devices()) != 1:
+            raise ValueError(
+                f"each share must sit on one device, got {len(raw.devices())}")
+
+    def build(raw):
+        with jax.default_device(next(iter(raw.devices()))):
+            return build_index(raw, segments, cardinality,
+                               refine_bits=refine_bits, impl=impl)
+
+    with ThreadPoolExecutor(len(shares)) as pool:
+        shards = tuple(pool.map(build, shares))
+    bounds = np.cumsum([0] + [raw.shape[0] for raw in shares])
+    return ShardedIndex(shards, tuple(int(b) for b in bounds))
 
 
 def validate_index(index: ParISIndex) -> dict:

@@ -1474,7 +1474,12 @@ def make_batch_engine(
     calls share) and wraps it so any (Q, n) call is padded up to the power-of-two
     bucket shape (pad rows repeat row 0 and are discarded) — one trace per
     bucket instead of one per arrival count, and a router can stamp out S
-    per-shard engines without retracing per query shape.
+    per-shard engines without retracing per query shape. The bucket is
+    padded on the host and put on the device that holds the index's raw
+    rows where they are committed to one device (as the shards of
+    ``build_sharded_index_on_devices`` are), so each shard's batches go
+    straight to its own device; an uncommitted index takes the default
+    device.
 
     ``k=None``: exact 1-NN, returns a ``SearchResult`` of (Q,) arrays.
     ``k >= 1``: exact k-NN, returns ((Q, k) dists ascending, (Q, k) pos)
@@ -1532,11 +1537,15 @@ def make_batch_engine(
         k_eff, round_size, leaf_cap, sort, select, impl, "approx", True,
     ) + ((extras[1],) if extras else ())
 
+    raw = getattr(index, "raw", None)
+    home = (next(iter(raw.devices())) if isinstance(raw, jax.Array)
+            and raw.committed and len(raw.devices()) == 1 else None)
+
     def bucket(qn: int) -> int:
         return pow2_bucket(qn, min_bucket)
 
     def engine(queries, tiers=None, counts=False):
-        qs = jnp.asarray(queries, jnp.float32)
+        qs = np.asarray(queries, np.float32)
         if qs.ndim != 2:
             raise ValueError(f"engine takes (Q, n) queries, got {qs.shape}")
         if counts and k is None:
@@ -1563,9 +1572,10 @@ def make_batch_engine(
                     "SearchResult mode answers tier='exact' only")
         b = bucket(qn)
         if b > qn:  # pad rows repeat a real query; sliced off below
-            qs = jnp.concatenate(
-                [qs, jnp.broadcast_to(qs[:1], (b - qn, qs.shape[1]))]
+            qs = np.concatenate(
+                [qs, np.broadcast_to(qs[:1], (b - qn, qs.shape[1]))]
             )
+        qs = jax.device_put(qs, home)
         if tiers is not None:
             eps_f, budget = tier_arrays(tiers)
             if b > qn:  # pad rows: factor 1, zero budget — inert rows

@@ -95,6 +95,7 @@ import time
 from concurrent.futures import Future, InvalidStateError
 from typing import List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.coldtier import ColdShard, make_cold_batch_engine
@@ -317,12 +318,13 @@ class _InFlight:
     ``parts[s]`` resolves exactly once per shard (("ok", result) or
     ("err", exc)); ``inflight``/``attempts``/``tried``/``hedged`` track
     the rescue machinery so an error is only final once no sibling
-    attempt can still answer.
+    attempt can still answer. ``first``/``last`` are the host times of
+    the first and the last shard answer.
     """
 
     __slots__ = ("out", "query", "deadline", "tier", "entries", "lock",
                  "parts", "inflight", "attempts", "tried", "hedged", "stash",
-                 "remaining")
+                 "remaining", "first", "last")
 
     def __init__(self, out: Future, query: np.ndarray,
                  deadline: Optional[float], tier: Tier, entries: list):
@@ -340,6 +342,7 @@ class _InFlight:
         self.hedged = [False] * n
         self.stash: List[Optional[BaseException]] = [None] * n
         self.remaining = n
+        self.first = self.last = None
 
 
 class ShardedSearchRouter:
@@ -467,7 +470,8 @@ class ShardedSearchRouter:
         self._started = False
         self._timer = _Timer()
         self._stats_lock = threading.Lock()
-        self._merge_stats = dict(merges=0, merge_ms_sum=0.0, merge_ms_max=0.0)
+        self._merge_stats = dict(merges=0, merge_ms_sum=0.0, merge_ms_max=0.0,
+                                 shard_wait_ms_sum=0.0, shard_wait_ms_max=0.0)
         self._fab = dict(
             shard_requests=0, retries=0, admission_retries=0, hedges=0,
             hedges_won=0, hedges_denied=0, deadline_expired=0,
@@ -681,8 +685,11 @@ class ShardedSearchRouter:
                 self._fab["shard_requests"] += len(entries)
             primaries = []
             try:
-                for s, e in enumerate(entries):
-                    primaries.append(self._primary(req, s, e))
+                # A profiler span, free unless a profile is being taken.
+                with jax.profiler.TraceAnnotation(
+                        "paris.route.fanout", shards=len(entries)):
+                    for s, e in enumerate(entries):
+                        primaries.append(self._primary(req, s, e))
             except BaseException as exc:
                 # A shard turned the request away mid-fan-out (after its
                 # sibling retry): the request fails as a whole. Shards
@@ -832,7 +839,8 @@ class ShardedSearchRouter:
     # ------------------------------------------------- sub-query lifecycle
     def _on_answer(self, req: _InFlight, s: int, entry: _RouterShard,
                    rep: _Replica, t0: float, kind: str, fut: Future) -> None:
-        lat_ms = (time.monotonic() - t0) * 1e3
+        now = time.monotonic()
+        lat_ms = (now - t0) * 1e3
         exc = fut.exception()
         if exc is None:
             rep.health.record_success(lat_ms)
@@ -844,6 +852,9 @@ class ShardedSearchRouter:
                 req.parts[s] = ("ok", res)
                 req.remaining -= 1
                 last = req.remaining == 0
+                if req.first is None:
+                    req.first = now
+                req.last = now
             if kind == "hedge":
                 with self._stats_lock:
                     self._fab["hedges_won"] += 1
@@ -932,18 +943,25 @@ class ShardedSearchRouter:
             self._try_set_exception(out, err)
             return
         try:
+            # Every part is an answer here: first to last shard answer.
+            wait_ms = (req.last - req.first) * 1e3
             t0 = time.perf_counter()
             results = [r for _, r in parts]
-            if self.k is None:
-                merged = self._merge_1nn(results, entries)
-            else:
-                merged = self._merge_knn(results, entries, req.tier)
+            with jax.profiler.TraceAnnotation(
+                    "paris.route.merge", shards=len(results),
+                    wait_ms=wait_ms):
+                if self.k is None:
+                    merged = self._merge_1nn(results, entries)
+                else:
+                    merged = self._merge_knn(results, entries, req.tier)
             dt_ms = (time.perf_counter() - t0) * 1e3
             with self._stats_lock:
                 m = self._merge_stats
                 m["merges"] += 1
                 m["merge_ms_sum"] += dt_ms
                 m["merge_ms_max"] = max(m["merge_ms_max"], dt_ms)
+                m["shard_wait_ms_sum"] += wait_ms
+                m["shard_wait_ms_max"] = max(m["shard_wait_ms_max"], wait_ms)
             self._try_set_result(out, merged)
         except BaseException as e:  # noqa: BLE001 — surface merge bugs
             self._try_set_exception(out, e)
@@ -1085,7 +1103,12 @@ class ShardedSearchRouter:
         snapshots, and the hedging/retry/deadline counters the fabric's
         rescue activity — together they let a caller spot saturation,
         a dead replica, or a melting hedge budget without poking
-        internals. Counts are cumulative: a caller that wants a rate
+        internals. ``merge_ms_*`` time the host merge of each answered
+        request and ``shard_wait_ms_*`` the time from its first shard
+        answer to its last; a profile has a span ``paris.route.merge``
+        (arguments ``shards``, ``wait_ms``) around each merge and
+        ``paris.route.fanout`` around each fan-out. Counts are
+        cumulative: a caller that wants a rate
         over a window takes the difference of two snapshots (as
         ``chipbench`` does).
         """
@@ -1145,8 +1168,11 @@ class ShardedSearchRouter:
                 / max(sum(s["tiered_answered"] for s in per)
                       + ret["tiered_answered"], 1)),
             merges=merge["merges"],
+            merge_ms_sum=merge["merge_ms_sum"],
             merge_ms_avg=merge["merge_ms_sum"] / max(merge["merges"], 1),
             merge_ms_max=merge["merge_ms_max"],
+            shard_wait_ms_sum=merge["shard_wait_ms_sum"],
+            shard_wait_ms_max=merge["shard_wait_ms_max"],
             per_shard=per,
             shard_ids=[sid for sid, _, _ in live],
             shard_offsets=[off for _, off, _ in live],
