@@ -396,10 +396,10 @@ def _cold_view(shard: ColdShard, *, leaf_cap: int, init: str,
 def _cold_engine_for(shard: ColdShard, statics: tuple):
     """Cached per-shard jitted engine (the cold ``_engine_for``).
 
-    Same statics key and same 5-/6-tuple contract as
-    ``core.search._engine_for``; the compiled closure bakes the hot
-    arrays in as constants and crosses to the host only at the
-    ``pure_callback`` raw reads.
+    Same statics key and same call and output contract as
+    ``core.search._engine_for``, the optional real-row count included;
+    the compiled closure bakes the hot arrays in as constants and crosses
+    to the host only at the ``pure_callback`` raw reads.
     """
     from repro.core.search import _engine_core
 
@@ -407,26 +407,15 @@ def _cold_engine_for(shard: ColdShard, statics: tuple):
     if fn is not None:
         return fn
     k, round_size, leaf_cap, sort, select, impl, init = statics[:7]
-    tiered = len(statics) > 7 and statics[7]
     blocks = statics[8] if len(statics) > 8 else None
 
-    if tiered:
-        @jax.jit
-        def fn(queries, eps_factor_sq, budget_rounds):
-            view = _cold_view(shard, leaf_cap=leaf_cap, init=init,
-                              blocks=blocks)
-            return _engine_core(
-                view, queries, k=k, round_size=round_size, sort=sort,
-                select=select, impl=impl, eps_factor_sq=eps_factor_sq,
-                budget_rounds=budget_rounds)
-    else:
-        @jax.jit
-        def fn(queries):
-            view = _cold_view(shard, leaf_cap=leaf_cap, init=init,
-                              blocks=blocks)
-            return _engine_core(
-                view, queries, k=k, round_size=round_size, sort=sort,
-                select=select, impl=impl)
+    @jax.jit
+    def fn(queries, eps_factor_sq=None, budget_rounds=None, n_real=None):
+        view = _cold_view(shard, leaf_cap=leaf_cap, init=init, blocks=blocks)
+        return _engine_core(
+            view, queries, k=k, round_size=round_size, sort=sort,
+            select=select, impl=impl, eps_factor_sq=eps_factor_sq,
+            budget_rounds=budget_rounds, n_real=n_real)
 
     shard._engines[statics] = fn
     return fn

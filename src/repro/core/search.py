@@ -54,7 +54,10 @@ Batched query answering (beyond-paper; MESSI-style multi-query execution):
                                BSF vector, per-query masked rounds, and a
                                joint early exit when every query's smallest
                                unprocessed lower bound exceeds its own BSF.
-  single-query API          -> :func:`exact_search` / :func:`exact_knn` are
+                               Once at most Q/8 rows (at least 8) are live,
+                               the loop and the fallback scan go on over
+                               those rows alone (:func:`_narrowing_loop`).
+  single-query API         -> :func:`exact_search` / :func:`exact_knn` are
                                thin Q=1 wrappers over the batch engine;
                                :func:`exact_search_single` keeps the original
                                one-query-at-a-time implementation as the
@@ -453,6 +456,57 @@ def select_candidates(lb: jax.Array, sel_len: int) -> tuple:
     return jax.lax.cond(exact, lambda lb: (lb_sel, order), full, lb)
 
 
+# Narrow RDC rounds: a batch of Q rows runs its rounds over the live rows
+# only once at most max(NARROW_MIN_ROWS, Q // 8) of them are live; batches
+# of NARROW_MIN_ROWS rows and fewer always run at full width.
+NARROW_MIN_ROWS = 8
+
+
+def _take_rows(x: jax.Array, slot: jax.Array) -> jax.Array:
+    """Rows ``slot`` of ``x``; an out-of-range slot reads a filler row of
+    +inf (floats) or 0 (ints, False), which no loop predicate admits."""
+    fill = jnp.inf if jnp.issubdtype(x.dtype, jnp.inexact) else 0
+    return jnp.take(x, slot, axis=0, mode="fill", fill_value=fill)
+
+
+def _narrowing_loop(cond, body, live, rows: dict, st: tuple) -> tuple:
+    """``lax.while_loop`` over a batch's rows, narrowed to its live rows.
+
+    ``rows`` holds the loop's per-row inputs and ``st`` is ``(r, *per-row
+    state)``, all with the batch's Q rows on axis 0. ``cond(rows, st)`` and
+    ``body(rows, st)`` are the loop's own, at any number of rows, and
+    ``live(rows, st)`` -> (Q,) marks the rows whose state a round can still
+    change: a row that is not live never becomes live again, and a round
+    leaves its state as it is. So a row's rounds give the same state
+    whatever width they run at.
+
+    With width W = max(``NARROW_MIN_ROWS``, Q // 8) < Q, the loop runs
+    over all Q rows while more than W are live, then goes on from the same
+    round over a (W, ...) gather of the live rows (empty slots are filler
+    rows, :func:`_take_rows`) and scatters their state back; a filler
+    slot writes nothing. Q <= W is the plain loop. Returns the final state
+    and the number of rounds that ran narrow.
+    """
+    n_q = st[1].shape[0]
+    width = max(NARROW_MIN_ROWS, n_q // 8)
+
+    def loop(rows, st, more=None):
+        def go(s):
+            c = cond(rows, s)
+            return c if more is None else c & more(rows, s)
+        return jax.lax.while_loop(go, functools.partial(body, rows), st)
+
+    if n_q <= width:
+        return loop(rows, st), jnp.int32(0)
+    st = loop(rows, st, lambda rows, s: jnp.sum(live(rows, s)) > width)
+    slot = jnp.nonzero(live(rows, st), size=width, fill_value=n_q)[0]
+    narrow = loop(jax.tree.map(lambda x: _take_rows(x, slot), rows),
+                  st[:1] + tuple(_take_rows(x, slot) for x in st[1:]))
+    merged = tuple(x.at[slot].set(y, mode="drop")
+                   for x, y in zip(st[1:], narrow[1:]))
+    return narrow[:1] + merged, narrow[0] - st[0]
+
+
 NO_POS = jnp.int32(-1)  # sentinel position of an unfilled k-NN result slot
 _NP_NO_POS = int(NO_POS)  # host-side value (np packing code, no tracing)
 
@@ -589,6 +643,7 @@ def _engine_core(
     eps_factor_sq: Optional[jax.Array] = None,
     budget_rounds: Optional[jax.Array] = None,
     seed0: Optional[tuple] = None,
+    n_real: Optional[jax.Array] = None,
 ) -> tuple:
     """THE batched RDC loop — the single engine core behind every search.
 
@@ -598,6 +653,20 @@ def _engine_core(
     and a joint early exit once no query's next lower bound beats its BSF.
     Storage layout (single index vs packed multi-component buffer) enters
     only through the :class:`EngineView` hooks.
+
+    The rounds run narrow (:func:`_narrowing_loop`): once at most
+    max(``NARROW_MIN_ROWS``, Q // 8) rows are live — their next bound (in
+    the fallback scan, their K-th selected bound) still below their k-th
+    distance — the main loop and the fallback scan go on over those rows
+    alone. Each row's candidates, masks, distances and merges are the same
+    at either width, so answers, reads, updates and rounds are too.
+
+    ``n_real`` (a traced int) says that only rows ``[0, n_real)`` are real:
+    the rest are pads, never live, so they neither extend a loop nor keep
+    it wide, and their outputs are meaningless. Given it, the outputs gain
+    a last element, the number of rounds (main loop and scan together)
+    that ran narrow. ``None`` means every row is real and keeps the
+    historical outputs.
 
     ``select="topk"`` keeps only the K smallest bounds per query
     (K = max(N/16, 4*round_size)), chosen by :func:`select_candidates` (two
@@ -692,13 +761,19 @@ def _engine_core(
             jnp.arange(n_rows, dtype=jnp.int32), padded, 0
         )
 
-    def _euclid_rows(raws):
+    # Per-row inputs of the rounds: what a narrowed loop gathers.
+    real = jnp.arange(n_q) < (n_q if n_real is None else n_real)
+    rows = {"qs": qs, "real": real}
+    if tiered:
+        rows.update(eps=eps_factor_sq, budget=budget_rounds)
+
+    def _euclid_rows(qs, raws):
         # (Q, rs, n) per-query candidates -> (Q, rs) distances.
         return jax.vmap(
             lambda q, rw: ops.euclid_sq(q, rw, impl=impl)
         )(qs, raws)
 
-    def _euclid_shared(raws):
+    def _euclid_shared(qs, raws):
         # (rs, n) candidates shared by every query -> (Q, rs) distances.
         return jax.vmap(lambda q: ops.euclid_sq(q, raws, impl=impl))(qs)
 
@@ -720,50 +795,61 @@ def _engine_core(
         neg_d, sel = jax.lax.top_k(-md, k)  # the TPU sorts the k + rs row
         return -neg_d, jnp.take_along_axis(mp, sel, axis=1)
 
-    def cond(st):
+    def head(rows, r):
+        return jax.lax.dynamic_slice_in_dim(
+            rows["lb"], r * rs, 1, axis=1)[:, 0]
+
+    def live(rows, st):
+        # A round changes a row only through candidates below its k-th
+        # distance; a tier merely narrows which of them it reads, and
+        # records the rest in skip_lb. The serial scan has no frontier.
+        if not sort:
+            return rows["real"]
+        return rows["real"] & (head(rows, st[0]) < st[1][:, -1])
+
+    def cond(rows, st):
         r, top_d = st[0], st[1]
         more = r < n_rounds
         if sort:  # joint early exit: every query's next bound >= its BSF
-            head = jax.lax.dynamic_slice_in_dim(
-                lb_sel_p, r * rs, 1, axis=1
-            )[:, 0]
+            h = head(rows, r)
             if tiered:
                 # A row is done when its scaled frontier meets its BSF
                 # (epsilon early stop; factor 1.0 == exact) or its round
                 # budget is spent.
-                active = r < budget_rounds
-                more &= jnp.any(active & (head * eps_factor_sq
-                                          < top_d[:, -1]))
+                go = ((r < rows["budget"])
+                      & (h * rows["eps"] < top_d[:, -1]))
             else:
-                more &= jnp.any(head < top_d[:, -1])
+                go = h < top_d[:, -1]
+            more &= jnp.any(rows["real"] & go)
         return more
 
-    def body(st):
+    def body(rows, st):
         if tiered:
             r, top_d, top_p, reads, updates, skip_lb = st
         else:
             r, top_d, top_p, reads, updates = st
         kth = top_d[:, -1]
-        lbs = jax.lax.dynamic_slice_in_dim(lb_sel_p, r * rs, rs, axis=1)
+        lbs = jax.lax.dynamic_slice_in_dim(rows["lb"], r * rs, rs, axis=1)
         if sort:
-            idx = jax.lax.dynamic_slice_in_dim(order_p, r * rs, rs, axis=1)
+            idx = jax.lax.dynamic_slice_in_dim(
+                rows["order"], r * rs, rs, axis=1)
             cand_pos = view.positions(idx)  # (Q, rs)
             raws = view.gather_raw(cand_pos)  # the "disk reads"
-            d = _euclid_rows(raws)
+            d = _euclid_rows(rows["qs"], raws)
         else:
             idx = jax.lax.dynamic_slice_in_dim(shared_order_p, r * rs, rs)
             pos1 = view.positions(idx)  # (rs,) row-order scan
             raws = view.gather_raw(pos1)
-            d = _euclid_shared(raws)
-            cand_pos = jnp.broadcast_to(pos1[None, :], (n_q, rs))
+            d = _euclid_shared(rows["qs"], raws)
+            cand_pos = jnp.broadcast_to(pos1[None, :], d.shape)
         if tiered:
             # The tier mask is a subset of the exact mask (factor >= 1):
             # candidates the exact engine would have checked but the tier
             # skips feed the achieved-bound tracker.
             would = lbs < kth[:, None]
             mask = (
-                (lbs * eps_factor_sq[:, None] < kth[:, None])
-                & (r < budget_rounds)[:, None]
+                (lbs * rows["eps"][:, None] < kth[:, None])
+                & (r < rows["budget"])[:, None]
             )
             skip_lb = jnp.minimum(
                 skip_lb,
@@ -789,9 +875,12 @@ def _engine_core(
            jnp.zeros((n_q,), jnp.int32))
     if tiered:
         st0 = st0 + (jnp.full((n_q,), INF),)
+    main_rows = dict(rows, lb=lb_sel_p)
+    if sort:
+        main_rows["order"] = order_p
     with jax.named_scope("paris.rdc"):
-        r, top_d, top_p, reads, updates, *skip = jax.lax.while_loop(
-            cond, body, st0)
+        (r, top_d, top_p, reads, updates, *skip), narrow = _narrowing_loop(
+            cond, body, live, main_rows, st0)
     r_main = r
     skip_lb = skip[0] if tiered else None
 
@@ -805,50 +894,59 @@ def _engine_core(
         # and its buffer copies — are skipped entirely. Its scope opens
         # inside the taken branch, so a batch that skips the scan runs no
         # op under ``paris.fallback`` (the cond op itself stays outside).
+        # The scan runs narrow as the main loop does, with ``need`` for
+        # live: most batches start it with a few rows in need, and then
+        # only its distances and merges shrink; its gather is shared.
         kth_bound = lb_sel[:, -1]
         all_rounds = -(-n_rows // rs)
         pad_all = all_rounds * rs
+
+        def flive(rows, fst):
+            return rows["real"] & (rows["kth_bound"] < fst[1][:, -1])
 
         @jax.named_scope("paris.fallback")
         def run_fallback(st):
             idx_all = _pad_to(
                 jnp.arange(n_rows, dtype=jnp.int32), pad_all, 0)
-            lb_all = _pad_cols(lb, pad_all, INF)
+            scan_rows = dict(rows, lb=_pad_cols(lb, pad_all, INF),
+                             kth_bound=kth_bound)
 
-            def fcond(fst):
+            def fcond(rows, fst):
                 r2, top_d = fst[0], fst[1]
                 if tiered:
-                    active = (r_main + r2) < budget_rounds
-                    return (r2 < all_rounds) & jnp.any(
-                        active
-                        & (kth_bound * eps_factor_sq < top_d[:, -1]))
-                return (r2 < all_rounds) & jnp.any(kth_bound < top_d[:, -1])
+                    active = (r_main + r2) < rows["budget"]
+                    go = active & (rows["kth_bound"] * rows["eps"]
+                                   < top_d[:, -1])
+                else:
+                    go = rows["kth_bound"] < top_d[:, -1]
+                return (r2 < all_rounds) & jnp.any(rows["real"] & go)
 
-            def fbody(fst):
+            def fbody(rows, fst):
                 if tiered:
                     r2, top_d, top_p, reads, updates, skip_lb = fst
                 else:
                     r2, top_d, top_p, reads, updates = fst
                 kth = top_d[:, -1]
+                kth_bound = rows["kth_bound"]
                 if tiered:
                     need = (
-                        (kth_bound * eps_factor_sq < kth)
-                        & ((r_main + r2) < budget_rounds)
+                        (kth_bound * rows["eps"] < kth)
+                        & ((r_main + r2) < rows["budget"])
                     )
                 else:
                     need = kth_bound < kth
                 lbs = jax.lax.dynamic_slice_in_dim(
-                    lb_all, r2 * rs, rs, axis=1)
+                    rows["lb"], r2 * rs, rs, axis=1)
                 idx = jax.lax.dynamic_slice_in_dim(idx_all, r2 * rs, rs)
                 pos1 = view.positions(idx)
                 raws = view.gather_raw(pos1)
-                d = _euclid_shared(raws)
+                d = _euclid_shared(rows["qs"], raws)
                 # lbs >= kth_bound skips candidates the main loop already
                 # processed (everything strictly below the K-th bound was
                 # in the selected list); ties at the bound re-distance
                 # harmlessly.
                 if tiered:
-                    gate = lbs * eps_factor_sq[:, None] < kth[:, None]
+                    gate = lbs * rows["eps"][:, None] < kth[:, None]
                 else:
                     gate = lbs < kth[:, None]
                 mask = (
@@ -869,7 +967,7 @@ def _engine_core(
                     )
                 d = jnp.where(mask, d, INF)
                 improved = jnp.min(d, axis=1) < kth
-                cand_pos = jnp.broadcast_to(pos1[None, :], (n_q, rs))
+                cand_pos = jnp.broadcast_to(pos1[None, :], d.shape)
                 top_d, top_p = merge(top_d, top_p, cand_pos, d)
                 out = (
                     r2 + 1,
@@ -882,27 +980,27 @@ def _engine_core(
                     out = out + (skip_lb,)
                 return out
 
-            return jax.lax.while_loop(fcond, fbody, st)
+            return _narrowing_loop(fcond, fbody, flive, scan_rows, st)
 
         st1 = (jnp.int32(0), top_d, top_p, reads, updates)
         if tiered:
             st1 = st1 + (skip_lb,)
             need0 = jnp.any(
-                (kth_bound * eps_factor_sq < top_d[:, -1])
+                real
+                & (kth_bound * eps_factor_sq < top_d[:, -1])
                 & (r_main < budget_rounds))
-            r2, top_d, top_p, reads, updates, skip_lb = jax.lax.cond(
-                need0, run_fallback, lambda st: st, st1
-            )
         else:
-            need0 = jnp.any(kth_bound < top_d[:, -1])
-            r2, top_d, top_p, reads, updates = jax.lax.cond(
-                need0, run_fallback, lambda st: st, st1
-            )
+            need0 = jnp.any(real & (kth_bound < top_d[:, -1]))
+        (r2, top_d, top_p, reads, updates, *skip), fb_narrow = jax.lax.cond(
+            need0, run_fallback, lambda st: (st, jnp.int32(0)), st1)
+        skip_lb = skip[0] if tiered else None
+        narrow = narrow + fb_narrow
         fb_r2, fb_all_rounds = r2, all_rounds
         r = r + r2
     else:
         fb_r2 = None
 
+    out = (top_d, top_p, reads, updates, r)
     if tiered:
         # Achieved squared error factor, per query: the BSF over the
         # smallest lower bound never distance-checked. Three sources of
@@ -927,9 +1025,10 @@ def _engine_core(
             denom = jnp.minimum(denom, trunc)
         achieved_sq = jnp.where(
             denom >= kth_final, jnp.float32(1.0), kth_final / denom)
-        return top_d, top_p, reads, updates, r, achieved_sq
-
-    return top_d, top_p, reads, updates, r
+        out = out + (achieved_sq,)
+    if n_real is not None:
+        out = out + (narrow,)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1391,7 +1490,8 @@ def exact_search_batch_packed(
 @functools.partial(jax.jit, static_argnames=("statics",))
 def _index_engine(index: ParISIndex, queries: jax.Array,
                   eps_factor_sq: Optional[jax.Array] = None,
-                  budget_rounds: Optional[jax.Array] = None, *,
+                  budget_rounds: Optional[jax.Array] = None,
+                  n_real: Optional[jax.Array] = None, *,
                   statics: tuple) -> tuple:
     k, round_size, leaf_cap, sort, select, impl, init = statics[:7]
     blocks = statics[8] if len(statics) > 8 else None
@@ -1399,6 +1499,7 @@ def _index_engine(index: ParISIndex, queries: jax.Array,
     return _engine_core(
         view, queries, k=k, round_size=round_size, sort=sort, select=select,
         impl=impl, eps_factor_sq=eps_factor_sq, budget_rounds=budget_rounds,
+        n_real=n_real,
     )
 
 
@@ -1409,9 +1510,12 @@ def _engine_for(index: ParISIndex, statics: tuple):
     gives the exact engine ``fn(queries)`` (historical 5-tuple return);
     appending ``True`` — ``(..., init, True)`` — the TIERED variant
     ``fn(queries, eps_factor_sq, budget_rounds)``, which returns the
-    6-tuple with the achieved factor. Tier parameters being traced is the
-    point: ONE compiled tiered engine per shape serves every epsilon and
-    budget in mixed batches. A ninth element — ``(..., init, tiered,
+    6-tuple with the achieved factor. Either takes the count of real rows
+    as a fourth positional argument, ``fn(queries, None, None, n_real)``
+    for the exact engine: the rows past it are pads, and the outputs gain
+    the rounds that ran narrow (:func:`_engine_core`). Tier parameters
+    and ``n_real`` being traced is the point: ONE compiled engine per
+    shape serves every epsilon, budget and fill of a batch. A ninth element — ``(..., init, tiered,
     (block_q, block_n))`` — carries an explicit kernel block-shape
     override (None members resolve through the tuning table); it is part
     of jit's cache key, so two block shapes compile two engines.
@@ -1472,9 +1576,13 @@ def make_batch_engine(
     ``ShardedSearchRouter``): it resolves the per-index jitted engine once
     (``_engine_for``, whose compiled programs direct ``exact_*_batch``
     calls share) and wraps it so any (Q, n) call is padded up to the power-of-two
-    bucket shape (pad rows repeat row 0 and are discarded) — one trace per
-    bucket instead of one per arrival count, and a router can stamp out S
-    per-shard engines without retracing per query shape. The bucket is
+    bucket shape — one trace per bucket instead of one per arrival count,
+    and a router can stamp out S per-shard engines without retracing per
+    query shape. Pad rows repeat row 0 and are discarded; the engine is
+    told the real-row count (traced, so one program serves every fill),
+    so pads are inert: they never extend the rounds, and a bucket holding
+    few real rows runs its rounds narrow over those (:func:`_engine_core`).
+    The bucket is
     padded on the host and put on the device that holds the index's raw
     rows where they are committed to one device (as the shards of
     ``build_sharded_index_on_devices`` are), so each shard's batches go
@@ -1490,14 +1598,14 @@ def make_batch_engine(
     achieved epsilon (:func:`achieved_epsilon`). ``tiers=None`` or
     all-exact takes the historical exact path, unchanged; a mixed batch
     compiles ONE extra tiered engine per bucket shape (tier parameters
-    are traced), and pad rows ride along with a zero round budget so
-    they can never extend the loop.
+    are traced); pad rows carry a zero round budget as well.
 
     ``engine(queries, counts=True)`` (k-NN mode only) appends the
     engine's work counts to whatever the call returns: the (Q,) raw reads
-    of the real rows and the round count, both copied to the host (pad
-    rows are left out). The answers are those of the plain call; 1-NN
-    mode's ``SearchResult`` carries the counts already.
+    of the real rows, the round count and how many of those rounds ran
+    narrow, all copied to the host (pad rows are left out). The answers
+    are those of the plain call; 1-NN mode's ``SearchResult`` carries
+    reads and rounds already.
 
     The returned callable exposes ``engine.bucket(qn)`` — the padded batch
     shape a Q-query call compiles at (callers use it for pad accounting) —
@@ -1508,7 +1616,9 @@ def make_batch_engine(
     :func:`_engine_for` serves in-memory :class:`ParISIndex` objects; the
     cold tier passes its own factory (``core.coldtier``) so a disk-backed
     shard rides the identical wrapper — same padding, tier, and sentinel
-    protocol — over its callback-gather engines.
+    protocol — over its callback-gather engines. Its engines are called
+    ``fn(qs, None, None, qn)`` and ``fn(qs, eps_factor_sq, budget_rounds,
+    qn)`` (see :func:`_engine_for`).
 
     ``block_q``/``block_n`` override the lower-bound kernel's block
     shapes for this engine; left ``None`` they resolve through the
@@ -1554,11 +1664,15 @@ def make_batch_engine(
                 "carries raw_reads and rounds")
         qn = qs.shape[0]
 
-        def with_counts(out, reads, rounds):
+        def with_counts(out, reads, rounds, narrow):
             # Host copies, sliced on the host: no per-size device program.
+            # ``narrow`` is the engine's outputs past the historical ones:
+            # an ``engine_for`` hook that leaves the narrow count out (a
+            # stub warming up this wrapper) serves every call but counts.
             if not counts:
                 return out
-            return out + (np.asarray(reads)[:qn], int(rounds))
+            return out + (np.asarray(reads)[:qn], int(rounds),
+                          int(narrow[0]))
         if tiers is not None:
             tiers = [as_tier(t) for t in tiers]
             if len(tiers) != qn:
@@ -1584,8 +1698,8 @@ def make_batch_engine(
                 budget = jnp.concatenate(
                     [budget, jnp.zeros((b - qn,), jnp.int32)])
             fnt = engine_for(index, tier_statics)
-            top_d, top_p, reads, updates, rounds, ach_sq = fnt(
-                qs, eps_f, budget)
+            top_d, top_p, reads, updates, rounds, ach_sq, *narrow = fnt(
+                qs, eps_f, budget, qn)
             top_d, top_p, ach_sq = top_d[:qn], top_p[:qn], ach_sq[:qn]
             if k_eff < k:
                 top_d = jnp.concatenate(
@@ -1593,8 +1707,9 @@ def make_batch_engine(
                 top_p = jnp.concatenate(
                     [top_p, jnp.full((qn, k - k_eff), NO_POS)], axis=1)
             return with_counts((top_d, top_p, achieved_epsilon(ach_sq)),
-                               reads, rounds)
-        top_d, top_p, reads, updates, rounds = fn(qs)
+                               reads, rounds, narrow)
+        top_d, top_p, reads, updates, rounds, *narrow = fn(
+            qs, None, None, qn)
         if k is None:
             return SearchResult(
                 top_d[:qn, 0], top_p[:qn, 0], reads[:qn], updates[:qn],
@@ -1606,7 +1721,7 @@ def make_batch_engine(
                 [top_d, jnp.full((qn, k - k_eff), INF)], axis=1)
             top_p = jnp.concatenate(
                 [top_p, jnp.full((qn, k - k_eff), NO_POS)], axis=1)
-        return with_counts((top_d, top_p), reads, rounds)
+        return with_counts((top_d, top_p), reads, rounds, narrow)
 
     engine.bucket = bucket
     engine.index = index

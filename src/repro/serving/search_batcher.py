@@ -477,7 +477,8 @@ class SearchRequestBatcher:
                     out = self._engine(qs, **kw)
                     d, pos = np.asarray(out[0]), np.asarray(out[1])
                     outs = [(d[i], pos[i]) for i in range(qn)]
-                counts = (dict(reads=int(np.sum(out[-2])), rounds=out[-1])
+                counts = (dict(reads=int(np.sum(out[-3])), rounds=out[-2],
+                               narrow_rounds=out[-1])
                           if kw else {})
         except BaseException as e:  # noqa: BLE001 — propagate per request
             for p in take:

@@ -72,7 +72,7 @@ def test_engine_counts_match_the_plain_call(index, queries, qn):
     qs = np.resize(queries, (qn, queries.shape[1]))
     engine = make_batch_engine(index, k=4, round_size=ROUND, min_bucket=8)
     d, p = engine(qs)
-    d2, p2, reads, rounds = engine(qs, counts=True)
+    d2, p2, reads, rounds, narrow = engine(qs, counts=True)
     np.testing.assert_array_equal(np.asarray(d2), np.asarray(d))
     np.testing.assert_array_equal(np.asarray(p2), np.asarray(p))
     _, _, want_reads, _, want_rounds = exact_knn_batch(
@@ -80,6 +80,7 @@ def test_engine_counts_match_the_plain_call(index, queries, qn):
     assert isinstance(reads, np.ndarray) and reads.shape == (qn,)
     np.testing.assert_array_equal(reads, np.asarray(want_reads))
     assert rounds == int(want_rounds)
+    assert narrow == 0  # a bucket of 8 rows never narrows
     with pytest.raises(ValueError, match="k-NN mode"):
         make_batch_engine(index, k=None, round_size=ROUND)(qs, counts=True)
 
@@ -106,6 +107,7 @@ def test_router_flush_records_its_spans(index, queries, tmp_path):
         index, jnp.asarray(queries), k=4, round_size=ROUND, stats=True)
     assert resolve[0]["reads"] == int(np.sum(reads))  # real rows only
     assert resolve[0]["rounds"] == int(rounds)
+    assert resolve[0]["narrow_rounds"] == 0  # a bucket of 8 rows
     assert resolve[0]["rows"] == index.num_series
     assert resolve[0]["qn"] == qn
 
